@@ -32,6 +32,7 @@ import numpy as np
 from benchmarks.common import HBM_BW, PEAK_FLOPS, emit, time_call
 from repro.deploy.calibrate import calibrate_vision
 from repro.deploy.planner import auto_budget, plan_mixed_precision
+from repro.parallel.ctx import make_mesh
 from repro.vision.configs import get_vision_config
 from repro.vision.models import (forward_int, init_fp, quantize_input,
                                  quantize_net, streamed_weight_bytes,
@@ -183,7 +184,7 @@ def main(nets=("mobilenet-tiny", "resnet8"), bits_sweep=(8, 4, 2),
                     print(f"# e2e: skipping {n_dev} devices "
                           f"(only {avail} available)")
                     continue
-                mesh = (None if n_dev == 1 else jax.make_mesh(
+                mesh = (None if n_dev == 1 else make_mesh(
                     (n_dev, 1), ("data", "model"),
                     devices=jax.devices()[:n_dev]))
                 fn = jax.jit(lambda xh, q=qnet, m=mesh: forward_int(

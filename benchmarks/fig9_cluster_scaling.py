@@ -35,6 +35,7 @@ from benchmarks.common import emit, time_call, PEAK_FLOPS, HBM_BW
 from repro.core import packing
 from repro.core.quantize import QuantizedLinearParams
 from repro.kernels import api
+from repro.parallel.ctx import make_mesh
 from repro.parallel.sharding import shard_packed_linear
 
 M, K, N = 256, 4608, 512
@@ -73,8 +74,8 @@ def main(devices=None, json_path="BENCH_cluster.json", backend=None,
                       f"(only {avail} available; set XLA_FLAGS="
                       f"--xla_force_host_platform_device_count={n_dev})")
                 continue
-            mesh = jax.make_mesh((1, n_dev), ("data", "model"),
-                                 devices=jax.devices()[:n_dev])
+            mesh = make_mesh((1, n_dev), ("data", "model"),
+                             devices=jax.devices()[:n_dev])
             sharded = shard_packed_linear(params, mesh)
             # jit so timing measures the compiled sharded GEMM, not
             # per-call shard_map retracing

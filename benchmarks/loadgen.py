@@ -112,6 +112,7 @@ def main(argv=None):
     import jax
     from repro.configs.qwen2p5_3b import smoke_config
     from repro.models.api import build
+    from repro.parallel.ctx import make_mesh
 
     cfg = smoke_config()
     model = build(cfg)
@@ -120,8 +121,8 @@ def main(argv=None):
     if args.mesh:
         dp = min(4, len(jax.devices()))
         tp = len(jax.devices()) // dp
-        mesh = jax.make_mesh((dp, tp), ("data", "model"),
-                             devices=jax.devices()[: dp * tp])
+        mesh = make_mesh((dp, tp), ("data", "model"),
+                         devices=jax.devices()[: dp * tp])
 
     workload = build_workload(cfg, args)
     print(f"workload: {args.requests} requests, qps={args.qps}, "

@@ -147,14 +147,16 @@ def unpack(p, bits: int, signed: bool, axis: int = -1):
 def _extract_field(container, bits: int, plane: int, signed: bool):
     """Extract bit-field ``plane`` from int8 containers, with sign/zero ext.
 
-    Works on int8 arrays with int8 ops only — safe inside Pallas kernels.
+    The shifts run on int32: Mosaic (TPU v5e) cannot lower int8 shifts, so
+    the container is sign-extended to 32 bits first and the field is
+    narrowed back to int8 at the end.
     """
-    c = container.astype(jnp.int8)
+    c = container.astype(jnp.int8).astype(jnp.int32)
     shift = bits * plane
     if signed:
         # left-align the field then arithmetic-shift right to sign-extend
-        left = 8 - bits - shift
-        return ((c << left) >> (8 - bits)).astype(jnp.int8)
+        left = 32 - bits - shift
+        return ((c << left) >> (32 - bits)).astype(jnp.int8)
     mask = (1 << bits) - 1
     return ((c >> shift) & mask).astype(jnp.int8)
 

@@ -507,7 +507,6 @@ def qdot_sharded(params, x_hat, *, mesh, dp_axis: str = "data",
     locally and the result is bit-exact vs single-device — no psum.
     The inner backend resolves on the *local* shard shape.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.parallel import sharding as shrules
 
@@ -540,12 +539,12 @@ def qdot_sharded(params, x_hat, *, mesh, dp_axis: str = "data",
     out = _run_counted(
         spec, "qdot", (x2.shape[0], k_pad, n), params.a_bits,
         params.w_bits, pipeline,
-        lambda: shard_map(
+        lambda: jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(dpe, None), wspecs["w_packed"], wspecs["kappa"],
                       wspecs["lam"], wspecs["m"],
                       P(tpe) if per_n else P()),
-            out_specs=P(dpe, tpe), check_rep=False)(
+            out_specs=P(dpe, tpe), check_vma=False)(
             x2, params.w_packed, params.kappa, params.lam, params.m, sc))
     return out[:m].reshape(*lead, n)
 
@@ -562,7 +561,6 @@ def qconv_sharded(params, x_hat, *, mesh, dp_axis: str = "data",
     bit-exactness argument as `qdot_sharded` — a device is a cluster core
     producing its own output-channel group.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.parallel import sharding as shrules
 
@@ -597,13 +595,13 @@ def qconv_sharded(params, x_hat, *, mesh, dp_axis: str = "data",
                   params.cout, getattr(params, "groups", 1))
     out = _run_counted(
         spec, "qconv", shape_glob, g.a_bits, g.w_bits, pipeline,
-        lambda: shard_map(
+        lambda: jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(dpe, None, None, None), wspecs["w_packed_fused"],
                       wspecs["gemm"]["w_packed"], wspecs["gemm"]["kappa"],
                       wspecs["gemm"]["lam"], wspecs["gemm"]["m"],
                       P(tpe) if per_n else P()),
-            out_specs=P(dpe, None, None, tpe), check_rep=False)(
+            out_specs=P(dpe, None, None, tpe), check_vma=False)(
             x, params.w_packed_fused, g.w_packed, g.kappa, g.lam, g.m, sc))
     return out[:nb]
 

@@ -8,22 +8,21 @@ conv) run the same per-tile pipeline from the paper:
     kappa*acc + lambda          (integer batch-norm, eq. 3)
     (m * .) >> d, clip          (QNT/ACT, eq. 4)  [epilogue='int']
 
-This module holds the pieces they share: the chunk-planar plane splitter
-(`subsplit`), the planar sub-byte dot product (`matmul_planes`), the three
-epilogues (`apply_epilogue`, int / dequant / raw), and block-shape
+This module holds the pieces they share: the chunk-planar plane re-cut
+(`recut_rows`), the planar sub-byte dot product (`matmul_planes`), the
+three epilogues (`apply_epilogue`, int / dequant / raw), and block-shape
 selection for both the GEMM grid (`default_block`) and the conv grid
 (`conv_default_block`).
 
-Field extraction is elementwise (shift+mask on int8 containers), so a
-plane of a packed block keeps the block's shape; planes of X pair
-one-to-one with planes of W because both sides use the same chunk-planar
+Field extraction is elementwise (shift+mask on the containers, widened to
+int32), so a plane of a packed block keeps the block's shape; planes of X
+pair with planes of W because both sides use the same chunk-planar
 logical K order and integer accumulation is order-invariant.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import packing
 from repro.core.quantize import requantize_shift
@@ -50,45 +49,36 @@ def check_pipeline(mode: str) -> str:
             f"{PIPELINE_MODES}")
     return mode
 
-# jax 0.4.x names the TPU compiler-params struct TPUCompilerParams; newer
-# releases renamed it CompilerParams. Resolve once here so every kernel
-# works against either.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
-
-def compiler_params(**kwargs):
-    return _COMPILER_PARAMS(**kwargs)
-
 
 def round_up(x: int, mult: int) -> int:
     return x + (-x) % mult
 
 
-def subsplit(planes, factor, axis):
-    """Split coarse chunk-planes into `factor`-finer planes along `axis`.
+def recut_rows(planes, pf_to: int):
+    """Re-cut the chunk-planar planes of a K-leading operand into ``pf_to``.
 
-    A plane of a pf-packed operand covers, per chunk, a contiguous logical
-    run of R = CHUNK // pf elements; the finer layout needs runs of
-    R // factor. Chunk order is shared, so this is a pure static reshape.
-    Fine plane q = p_coarse * factor + f.
+    Plane p of a pf-packed operand holds, for chunk c, the logical run
+    ``c*CHUNK + p*R + [0, R)`` (R = CHUNK // pf) at rows ``c*R + [0, R)``.
+    The re-cut planes hold the same runs at R' = CHUNK // pf_to. It is
+    built from static row slices and a concatenate along rows: every piece
+    is at least 32 rows (one int8 sublane tile), which Mosaic lowers, where
+    a reshape that splits the lane axis is refused.
     """
-    if factor == 1:
+    pf_from = len(planes)
+    if pf_from == pf_to:
         return planes
-    pf_coarse = len(planes)
-    run = packing.CHUNK // pf_coarse
-    fine_run = run // factor
+    run_from, run_to = packing.CHUNK // pf_from, packing.CHUNK // pf_to
+    piece = min(run_from, run_to)
+    n_chunks = planes[0].shape[0] // run_from
     out = []
-    for p in planes:
-        if axis == 0:
-            k, n = p.shape
-            q = p.reshape(k // run, factor, fine_run, n)
-            out.extend(q[:, f].reshape(k // factor, n) for f in range(factor))
-        else:
-            m, k = p.shape
-            q = p.reshape(m, k // run, factor, fine_run)
-            out.extend(q[:, :, f].reshape(m, k // factor)
-                       for f in range(factor))
+    for q in range(pf_to):
+        parts = []
+        for c in range(n_chunks):
+            for t in range(q * run_to, (q + 1) * run_to, piece):
+                p, j = divmod(t, run_from)
+                r = c * run_from + j
+                parts.append(planes[p][r:r + piece])
+        out.append(jnp.concatenate(parts, axis=0))
     return out
 
 
@@ -97,16 +87,14 @@ def matmul_planes(x_block, w_block, a_bits, a_signed, w_bits):
 
     x_block: (bm, bk/pf_a) packed containers, K along axis 1.
     w_block: (bk/pf_w, bn) packed containers, K along axis 0.
-    Both sides must share the chunk-planar logical K order.
+    Both sides must share the chunk-planar logical K order. The
+    activation planes are used as unpacked (K on lanes); the weight planes
+    (K on sublanes) are re-cut to the activation's plane layout.
     """
-    pf_a = packing.pack_factor(a_bits)
-    pf_w = packing.pack_factor(w_bits)
     x_planes = packing.unpack_planes(x_block, a_bits, a_signed)
-    w_planes = packing.unpack_planes(w_block, w_bits, True)  # weights signed
-
-    pf = max(pf_a, pf_w)
-    x_planes = subsplit(x_planes, pf // pf_a, axis=1)
-    w_planes = subsplit(w_planes, pf // pf_w, axis=0)
+    w_planes = recut_rows(
+        packing.unpack_planes(w_block, w_bits, True),  # weights signed
+        len(x_planes))
 
     acc = None
     for xp, wp in zip(x_planes, w_planes):
@@ -260,8 +248,9 @@ def conv_working_set(bho, bn, *, ho, wo, cout, fh, fw, cin_pad, stride,
     cp = cin_pad // pf_a
     kp = fh * fw * cin_pad // pf_w
     n_tiles = -(-ho // bho)
-    hp = n_tiles * bho * stride + fh          # >= (ho_pad-1)*s + fh
-    wp = wo * stride + fw                     # >= (wo-1)*s + fw
+    # upper bounds of the wrapper's stride-phase image extents
+    hp = round_up(n_tiles * bho * stride + fh, stride)
+    wp = round_up(wo * stride + fw, 8 * stride)
     bm = bho * wo
     img = hp * wp * cp                        # packed int8 image block
     w_b = kp * bn                             # packed weight panel

@@ -47,15 +47,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import packing
-from repro.kernels.common import (EPILOGUE_DTYPES, apply_epilogue,
-                                  check_pipeline, compiler_params,
+from repro.kernels.common import (LANE, SUBLANE_I8, EPILOGUE_DTYPES,
+                                  apply_epilogue, check_pipeline,
                                   default_block, matmul_planes,
                                   segmented_bk, segmented_default_block)
-
-# Back-compat re-exports: these lived here before the kernels/common split.
-from repro.kernels.common import (LANE, SUBLANE_I8,  # noqa: F401
-                                  matmul_planes as _matmul_planes,
-                                  subsplit as _subsplit)
 
 
 def _qmatmul_kernel(x_ref, w_ref, kappa_ref, lam_ref, m_ref, o_ref, acc_ref,
@@ -200,7 +195,7 @@ def qmatmul_packed(x, w_packed, kappa, lam, m_mul, *,
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((bm, bn), jnp.int32),
             ],
-            compiler_params=compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
         )(x, w_packed, kappa.reshape(1, -1), lam.reshape(1, -1),
@@ -224,40 +219,43 @@ def qmatmul_packed(x, w_packed, kappa, lam, m_mul, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mdim, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_packed, kappa.reshape(1, -1), lam.reshape(1, -1),
       m_mul.reshape(1, -1))
 
 
-def _qmatmul_segmented_kernel(code_ref, off_ref, x_ref, kappa_ref, lam_ref,
+def _qmatmul_segmented_kernel(code_ref, row_ref, x_ref, kappa_ref, lam_ref,
                               m_ref, w_hbm, o_ref, w_buf, sems, acc_ref,
                               *, nk: int, bk: int, widths, a_bits: int,
                               a_signed: bool, d: int, out_bits: int,
                               epilogue: str, scale: float, pipeline: str):
     """Mixed-operand GEMM tile (fine-grain mixed precision, 2307.01056).
 
-    One grid step owns one (bm, LANE) output tile. The weight panel for
-    N-tile j lives at byte offset ``off_ref[j]`` in the flat segmented
-    buffer, packed at width ``widths[code_ref[j]]`` — both scalars arrive
-    via prefetch, so the kernel picks its DMA size and planar unpack
-    width per tile with a `jax.lax.switch` over the (static) width set.
-    K loops inside the kernel: panel-major layout makes tile kk of the
-    panel the contiguous byte range [off + kk*sz, off + (kk+1)*sz).
+    One grid step owns one (bm, LANE) output tile. The flat segmented
+    buffer arrives as rows of LANE bytes; the weight panel for N-tile j
+    starts at row ``row_ref[j]`` and is packed at width
+    ``widths[code_ref[j]]`` — both scalars arrive via prefetch, so the
+    kernel picks its DMA size and planar unpack width per tile with a
+    `jax.lax.switch` over the (static) width set. K loops inside the
+    kernel: panel-major layout makes tile kk of the panel the contiguous
+    rows [row + kk*r, row + (kk+1)*r), r = bk / pf.
     """
     j = pl.program_id(1)
     code = code_ref[j]
-    base = off_ref[j]
+    base = row_ref[j]
     pf_a = packing.pack_factor(a_bits)
     bka = bk // pf_a
-    sizes = [bk // packing.pack_factor(b) * LANE for b in widths]
+    rows = [bk // packing.pack_factor(b) for b in widths]
 
     def dma(slot, kk, wi):
-        sz = sizes[wi]
+        r = rows[wi]
+        # every panel and K tile spans a multiple of 32 rows (CHUNK / 4)
+        start = pl.multiple_of(base + kk * r, SUBLANE_I8)
         return pltpu.make_async_copy(
-            w_hbm.at[pl.dslice(base + kk * sz, sz)],
-            w_buf.at[slot, pl.dslice(0, sz)], sems.at[slot])
+            w_hbm.at[pl.ds(start, r)],
+            w_buf.at[slot, pl.ds(0, r)], sems.at[slot])
 
     def start(slot, kk):
         jax.lax.switch(code, [
@@ -270,11 +268,10 @@ def _qmatmul_segmented_kernel(code_ref, off_ref, x_ref, kappa_ref, lam_ref,
             for wi in range(len(widths))])
 
     def tile_dot(slot, kk):
-        xb = x_ref[:, pl.dslice(kk * bka, bka)]
+        xb = x_ref[:, pl.ds(pl.multiple_of(kk * bka, bka), bka)]
 
         def dot_at(wi):
-            rows = bk // packing.pack_factor(widths[wi])
-            wb = w_buf[slot, pl.dslice(0, rows * LANE)].reshape(rows, LANE)
+            wb = w_buf[slot, :rows[wi], :]
             return matmul_planes(xb, wb, a_bits, a_signed, widths[wi])
 
         return jax.lax.switch(code, [
@@ -325,7 +322,7 @@ def qmatmul_segmented(x, w_flat, segmap, kappa, lam, m_mul, *,
     CHUNK/LANE multiple — callers `packing.pad_segmented` first. The grid
     is (M/bm, N/LANE): each N tile is exactly one CHUNK-wide column
     panel, so a tile never straddles a segment boundary and its unpack
-    width + byte offset come from the prefetched per-tile descriptor
+    width + panel offset come from the prefetched per-tile descriptor
     (`segmap.tile_table`). K loops inside the kernel with manual DMA from
     the flat buffer — 'off' copies/waits/dots serially per K tile,
     'double_buffer' rotates two slots with the next tile's copy issued
@@ -352,7 +349,7 @@ def qmatmul_segmented(x, w_flat, segmap, kappa, lam, m_mul, *,
     assert mdim % bm == 0, (mdim, bm)
     nk = k_pad // bk
     nslots = 2 if pipeline == "double_buffer" else 1
-    slot_bytes = bk // min(packing.pack_factor(b) for b in widths) * LANE
+    slot_rows = bk // min(packing.pack_factor(b) for b in widths)
 
     codes, offs = segmap.tile_table(k_logical)
     if out_dtype is None:
@@ -375,7 +372,7 @@ def qmatmul_segmented(x, w_flat, segmap, kappa, lam, m_mul, *,
         ],
         out_specs=pl.BlockSpec((bm, LANE), lambda i, j, *_: (i, j)),
         scratch_shapes=[
-            pltpu.VMEM((nslots, slot_bytes), jnp.int8),
+            pltpu.VMEM((nslots, slot_rows, LANE), jnp.int8),
             pltpu.SemaphoreType.DMA((nslots,)),
             pltpu.VMEM((bm, LANE), jnp.int32),
         ])
@@ -383,9 +380,9 @@ def qmatmul_segmented(x, w_flat, segmap, kappa, lam, m_mul, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mdim, n), out_dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(jnp.asarray(codes, jnp.int32), jnp.asarray(offs, jnp.int32),
+    )(jnp.asarray(codes, jnp.int32), jnp.asarray(offs // LANE, jnp.int32),
       x, kappa.reshape(1, -1), lam.reshape(1, -1), m_mul.reshape(1, -1),
-      w_flat)
+      w_flat.reshape(-1, LANE))

@@ -15,7 +15,6 @@ from repro.configs.base import SHAPES
 from repro.launch.hlo_costs import analyze
 from repro.launch.mesh import make_production_mesh
 from repro.models.api import build, get_config
-from repro.parallel.ctx import use_mesh
 from repro.train.step import (TrainStepConfig, make_decode_fns,
                               make_prefill_fns, make_train_fns)
 
@@ -43,7 +42,7 @@ def compile_cell(arch, shape_name, mesh_kind="pod", quant="off", rules=None):
                                                **kwargs)
         ss = jax.eval_shape(init_fn, jax.ShapeDtypeStruct((2,), jnp.uint32))
         ins = model.input_specs(shape)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             return jax.jit(step, in_shardings=(shards["state"],
                                                shards["batch"]),
                            out_shardings=(shards["state"], None),
@@ -53,7 +52,7 @@ def compile_cell(arch, shape_name, mesh_kind="pod", quant="off", rules=None):
         ps = jax.eval_shape(lambda k: model.init(k),
                             jax.ShapeDtypeStruct((2,), jnp.uint32))
         ins = model.input_specs(shape)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             return jax.jit(step, in_shardings=(shards["params"],
                                                shards["batch"])
                            ).lower(ps, ins).compile()
@@ -61,7 +60,7 @@ def compile_cell(arch, shape_name, mesh_kind="pod", quant="off", rules=None):
     ps = jax.eval_shape(lambda k: model.init(k),
                         jax.ShapeDtypeStruct((2,), jnp.uint32))
     ins = model.input_specs(shape)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.jit(step, in_shardings=(
             shards["params"], shards["cache"], shards["token"],
             shards["index"]),

@@ -26,6 +26,7 @@ from repro.deploy.apply import apply_plan
 from repro.deploy.calibrate import calibrate
 from repro.deploy.planner import auto_budget, plan_mixed_precision
 from repro.deploy.policy import PLAN_VERSION, load_plan, save_plan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.convert import artifact_bytes
 from repro.models.api import Model, build, get_config
 from repro.nn.layers import QuantConfig
@@ -57,6 +58,7 @@ def main():
                     help="checkpoint dir to load fp params from")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         from repro.models.api import get_smoke_config

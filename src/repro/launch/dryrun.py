@@ -37,7 +37,6 @@ from repro.launch.hlo_costs import analyze as analyze_hlo
 from repro.launch.mesh import make_production_mesh
 from repro.models.api import build, get_config, list_archs
 from repro.nn.module import param_count
-from repro.parallel.ctx import use_mesh
 from repro.train.step import (TrainStepConfig, make_decode_fns,
                               make_prefill_fns, make_train_fns)
 
@@ -96,7 +95,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
                                              shards["batch"]),
                          out_shardings=(shards["state"], None),
                          donate_argnums=(0,))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(state_shapes, in_specs)
     elif shape.kind == "prefill":
         step, shards = make_prefill_fns(model, mesh, shape, **kwargs)
@@ -105,7 +104,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         in_specs = model.input_specs(shape)
         jitted = jax.jit(step, in_shardings=(shards["params"],
                                              shards["batch"]))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(pshapes, in_specs)
     else:  # decode
         step, shards = make_decode_fns(model, mesh, shape, **kwargs)
@@ -116,7 +115,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
             shards["params"], shards["cache"], shards["token"],
             shards["index"]),
             out_shardings=(None, shards["cache"]), donate_argnums=(1,))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(pshapes, in_specs["cache"],
                                    in_specs["token"], in_specs["index"])
     t_lower = time.time() - t0

@@ -9,15 +9,17 @@ from __future__ import annotations
 
 import jax
 
+from repro.parallel.ctx import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Tiny mesh over whatever devices exist (tests / examples on CPU)."""
     n = len(jax.devices())
     data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -72,6 +72,8 @@ def main():
     from repro.deploy.planner import auto_budget, plan_mixed_precision
     from repro.deploy.policy import save_plan
     from repro.obs import trace as obs
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.parallel.ctx import make_mesh
     from repro.qat.data import make_dataset
     from repro.qat.evaluate import deploy, evaluate_int, fold_check
     from repro.qat.train import QATConfig, train_qat
@@ -85,11 +87,11 @@ def main():
                         data_dir=args.data_dir)
     candidates = tuple(int(b) for b in args.bits.split(","))
 
+    enable_compile_cache()
     mesh = None
     if args.mesh:
         dp = int(args.mesh.split(",")[0])
-        mesh = jax.make_mesh((dp,), ("data",),
-                             devices=jax.devices()[:dp])
+        mesh = make_mesh((dp,), ("data",), devices=jax.devices()[:dp])
 
     qc = QATConfig(steps=args.steps, batch=args.batch, lr=args.lr,
                    warmup=args.warmup,

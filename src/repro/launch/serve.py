@@ -31,11 +31,64 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.convert import convert_params
 from repro.models.api import build, get_config
 from repro.nn.layers import QuantConfig
 from repro.obs import trace as obs
+from repro.parallel.ctx import make_mesh
 from repro.serve.engine import Engine, Request
+
+
+def build_serving(cfg, *, quant: str = "off", plan_path=None, ckpt=None,
+                  seed: int = 0):
+    """Build the served model and its parameter tree.
+
+    The float tree is initialised (or restored) and quantized on the host
+    CPU, where a full-width float32 checkpoint fits; only the tree that is
+    served (packed when ``quant``/``plan_path`` ask for it) is put on the
+    default device. Returns ``(model, params, plan, mode)``.
+    """
+    with jax.default_device(jax.local_devices(backend="cpu")[0]):
+        fp_model = build(cfg)
+        if ckpt:
+            from repro.ckpt.checkpoint import restore
+            state, _ = restore(ckpt)
+            fp_params = state["params"] if "params" in state else state
+        else:
+            fp_params = fp_model.init(jax.random.PRNGKey(seed))
+
+        plan = None
+        if plan_path:
+            from repro.deploy.apply import apply_plan
+            from repro.deploy.policy import load_plan
+            plan = load_plan(plan_path)
+            qcfg = QuantConfig(mode="int", w_bits=plan.default_w_bits,
+                               a_bits=plan.default_a_bits)
+            model = build(dataclasses.replace(cfg, quant=qcfg,
+                                              quant_plan=plan))
+            params = apply_plan(model.init(jax.random.PRNGKey(0)),
+                                fp_params, plan, plan.default_w_bits)
+            mode = f"plan:{plan_path} w_bits={plan.distinct_w_bits()}"
+        elif quant != "off":
+            qcfg = QuantConfig(mode="int", w_bits=int(quant[1]),
+                               a_bits=int(quant[3]))
+            model = build(dataclasses.replace(cfg, quant=qcfg))
+            params = convert_params(model.init(jax.random.PRNGKey(0)),
+                                    fp_params, qcfg.w_bits)
+            mode = quant
+        else:
+            model, params = fp_model, fp_params
+            mode = "off"
+    return model, jax.device_put(params, jax.devices()[0]), plan, mode
+
+
+def make_requests(cfg, n: int, max_new: int, seed: int = 0):
+    """``n`` synthetic requests: prompts of 2-7 random tokens."""
+    rng = np.random.default_rng(seed)
+    return [Request(prompt=rng.integers(2, cfg.vocab, size=(
+        int(rng.integers(2, 8)),)).astype(np.int32),
+        max_new_tokens=max_new) for _ in range(n)]
 
 
 def main():
@@ -61,6 +114,7 @@ def main():
                          "On CPU, export XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N first")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = None
     if args.mesh:
@@ -77,8 +131,8 @@ def main():
                 f"--mesh {args.mesh} needs {need} devices, found {have}; "
                 "on CPU set XLA_FLAGS=--xla_force_host_platform_"
                 f"device_count={need} before launching")
-        mesh = jax.make_mesh((dp, tp), ("data", "model"),
-                             devices=jax.devices()[:need])
+        mesh = make_mesh((dp, tp), ("data", "model"),
+                         devices=jax.devices()[:need])
 
     if args.smoke:
         from repro.models.api import get_smoke_config
@@ -86,48 +140,16 @@ def main():
     else:
         cfg = get_config(args.arch)
     cfg = dataclasses.replace(cfg, kv_quant_bits=args.kv_bits)
-
-    fp_model = build(cfg)
-    if args.ckpt:
-        from repro.ckpt.checkpoint import restore
-        state, _ = restore(args.ckpt)
-        fp_params = state["params"] if "params" in state else state
-    else:
-        fp_params = fp_model.init(jax.random.PRNGKey(args.seed))
-
-    plan = None
-    if args.plan:
-        from repro.deploy.apply import apply_plan
-        from repro.deploy.policy import load_plan
-        plan = load_plan(args.plan)
-        qcfg = QuantConfig(mode="int", w_bits=plan.default_w_bits,
-                           a_bits=plan.default_a_bits)
-        cfg_q = dataclasses.replace(cfg, quant=qcfg, quant_plan=plan)
-        model = build(cfg_q)
-        params = apply_plan(model.init(jax.random.PRNGKey(0)), fp_params,
-                            plan, plan.default_w_bits)
-        mode = f"plan:{args.plan} w_bits={plan.distinct_w_bits()}"
-    elif args.quant != "off":
-        qcfg = QuantConfig(mode="int", w_bits=int(args.quant[1]),
-                           a_bits=int(args.quant[3]))
-        cfg_q = dataclasses.replace(cfg, quant=qcfg)
-        model = build(cfg_q)
-        params = convert_params(model.init(jax.random.PRNGKey(0)),
-                                fp_params, qcfg.w_bits)
-        mode = args.quant
-    else:
-        model, params = fp_model, fp_params
-        mode = "off"
+    model, params, plan, mode = build_serving(
+        cfg, quant=args.quant, plan_path=args.plan, ckpt=args.ckpt,
+        seed=args.seed)
 
     from repro.nn.module import param_bytes
     pbytes = param_bytes(params)
     print(f"{cfg.name} [{mode}] params {pbytes / 2**20:.1f} MiB "
           f"({pbytes:,} bytes)")
 
-    rng = np.random.default_rng(args.seed)
-    reqs = [Request(prompt=rng.integers(2, cfg.vocab, size=(
-        int(rng.integers(2, 8)),)).astype(np.int32),
-        max_new_tokens=args.max_new) for _ in range(args.requests)]
+    reqs = make_requests(cfg, args.requests, args.max_new, args.seed)
     eng = Engine(model, params, batch_size=args.batch, max_len=args.max_len,
                  plan=plan, mesh=mesh)
     if mesh is not None:
@@ -145,8 +167,9 @@ def main():
         out = eng.generate(reqs)
     dt = time.time() - t0
     toks = sum(len(r.out) for r in out)
-    print(f"{toks} tokens / {dt:.2f}s = {toks / dt:.1f} tok/s (CPU, "
-          f"structure-comparative only)")
+    dev = jax.devices()[0]
+    print(f"{toks} tokens / {dt:.2f}s = {toks / dt:.1f} tok/s on "
+          f"{dev.platform}:{dev.device_kind} (includes compilation)")
     rep = eng.utilization_report()
     lat = rep["latency_us"]
     if lat is not None:

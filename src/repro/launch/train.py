@@ -17,13 +17,13 @@ import jax
 
 from repro.configs.base import SHAPES, ShapeConfig
 from repro.data.pipeline import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.api import build, get_config
 from repro.nn.layers import QuantConfig
 from repro.runtime.trainer import Trainer, TrainerConfig
 from repro.train.optimizer import OptConfig
 from repro.train.step import TrainStepConfig, make_train_fns
-from repro.parallel.ctx import use_mesh
 
 
 def main():
@@ -46,6 +46,7 @@ def main():
                     choices=[32, 8])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         from repro.models.api import get_smoke_config
@@ -70,7 +71,7 @@ def main():
         else 0,
         src_len=args.seq if cfg.family == "encdec" else cfg.src_len)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jstep = jax.jit(step, in_shardings=(shards["state"],
                                             shards["batch"]),
                         out_shardings=(shards["state"], None),

@@ -19,6 +19,37 @@ from __future__ import annotations
 import argparse
 
 
+def calibrate_and_plan(cfg, fp_params, batches, *, candidates,
+                       budget="auto", a_bits: int = 8, backend=None,
+                       smoke: bool = False):
+    """Calibrate ``cfg`` on image ``batches`` and search a per-layer
+    W{candidates} plan under ``budget`` ('auto' or a float). Prints one
+    line per layer; returns ``(plan, absmax)``."""
+    from repro.deploy.calibrate import calibrate_vision
+    from repro.deploy.planner import auto_budget, plan_mixed_precision
+    from repro.obs import trace as obs
+
+    print(f"calibrating {cfg.name}: {len(batches)} batches of "
+          f"{len(batches[0])} images {cfg.in_hw}, candidates W{candidates}")
+    with obs.span("deploy.calibrate", cat="deploy", arch=cfg.name,
+                  batches=len(batches), candidates=candidates):
+        stats, absmax = calibrate_vision(cfg, fp_params, batches,
+                                         bits=candidates)
+    with obs.span("deploy.plan", cat="deploy", arch=cfg.name,
+                  paths=len(stats)):
+        budget = (auto_budget(stats, candidates) if budget == "auto"
+                  else float(budget))
+        plan = plan_mixed_precision(
+            stats, budget, candidates=candidates, a_bits=a_bits,
+            backend=backend, meta={"arch": cfg.name, "smoke": smoke})
+    for r in plan.rules:
+        st = stats[r.pattern]
+        print(f"  {r.pattern:<16} W{r.w_bits}A{r.a_bits}  "
+              f"absmax={st.a_absmax:.3f}  sens="
+              f"{{{', '.join(f'{b}:{st.sens(b):.2e}' for b in candidates)}}}")
+    return plan, absmax
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--net", required=True,
@@ -48,14 +79,15 @@ def main():
     import jax
     import numpy as np
 
-    from repro.deploy.calibrate import calibrate_vision
-    from repro.deploy.planner import auto_budget, plan_mixed_precision
     from repro.deploy.policy import load_plan, save_plan
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.parallel.ctx import make_mesh
     from repro.serve.engine import VisionEngine
     from repro.vision.configs import get_vision_config
     from repro.vision.models import (collect_absmax, init_fp, quantize_net,
                                      vision_artifact_bytes)
 
+    enable_compile_cache()
     mesh = None
     if args.mesh:
         try:
@@ -68,8 +100,8 @@ def main():
                 f"--mesh {args.mesh} needs {need} devices, found {have}; "
                 "set XLA_FLAGS=--xla_force_host_platform_device_count="
                 f"{need}")
-        mesh = jax.make_mesh((dp, tp), ("data", "model"),
-                             devices=jax.devices()[:need])
+        mesh = make_mesh((dp, tp), ("data", "model"),
+                         devices=jax.devices()[:need])
 
     cfg = get_vision_config(args.net, smoke=args.smoke, a_bits=args.a_bits)
     candidates = tuple(int(b) for b in args.bits.split(","))
@@ -87,26 +119,10 @@ def main():
         print(f"loaded plan {args.from_plan} ({len(plan.rules)} rules, "
               f"w_bits {plan.distinct_w_bits()})")
     else:
-        print(f"calibrating {cfg.name}: {len(batches)} batches of "
-              f"{args.calib_batch} images {cfg.in_hw}, "
-              f"candidates W{candidates}")
-        with obs.span("deploy.calibrate", cat="deploy", arch=cfg.name,
-                      batches=len(batches), candidates=candidates):
-            stats, absmax = calibrate_vision(cfg, fp_params, batches,
-                                             bits=candidates)
-        with obs.span("deploy.plan", cat="deploy", arch=cfg.name,
-                      paths=len(stats)):
-            budget = (auto_budget(stats, candidates)
-                      if args.budget == "auto" else float(args.budget))
-            plan = plan_mixed_precision(
-                stats, budget, candidates=candidates, a_bits=args.a_bits,
-                backend=args.backend,
-                meta={"arch": cfg.name, "smoke": args.smoke})
-        for r in plan.rules:
-            st = stats[r.pattern]
-            print(f"  {r.pattern:<16} W{r.w_bits}A{r.a_bits}  "
-                  f"absmax={st.a_absmax:.3f}  sens="
-                  f"{{{', '.join(f'{b}:{st.sens(b):.2e}' for b in candidates)}}}")
+        plan, absmax = calibrate_and_plan(
+            cfg, fp_params, batches, candidates=candidates,
+            budget=args.budget, a_bits=args.a_bits, backend=args.backend,
+            smoke=args.smoke)
         save_plan(plan, args.out)
         print(f"plan ({len(plan.rules)} rules, w_bits "
               f"{plan.distinct_w_bits()}) -> {args.out}")
@@ -123,7 +139,9 @@ def main():
     if mesh is not None:
         print(f"mesh: data={mesh.shape['data']} model={mesh.shape['model']}"
               f" ({len(mesh.devices.flat)} devices)")
-    print(f"kernel backends: {engine.kernel_backends()}")
+    dev = jax.devices()[0]
+    print(f"kernel backends on {dev.platform}:{dev.device_kind}: "
+          f"{engine.kernel_backends()}")
     images = rng.uniform(0, 1, size=(
         args.requests, *cfg.in_hw, cfg.in_ch)).astype(np.float32)
     with obs.span("serve.generate", cat="serve", requests=len(images),

@@ -1,13 +1,14 @@
 """Activation-sharding context: logical constraints inside model code.
 
 **Paper analogy (XpulpNN §V):** an active mesh is the paper's parallel
-cluster — one JAX device per cluster core. `use_mesh` is the repo-wide
-way to enter that cluster context; everything layered above
-(`repro.kernels.api.qdot_sharded`, the serve engine's wave sharding, the
-GSPMD constraints below) assumes it. Packed sub-byte arrays inside the
-context obey the invariants in `repro.parallel.sharding`: sharded only on
-the output-feature axis, never on the packed reduction axis (a shard
-boundary inside a CHUNK group would split int8 containers across cores).
+cluster — one JAX device per cluster core. `make_mesh` is the repo-wide
+way to build that cluster, and `jax.set_mesh` enters it; everything
+layered above (`repro.kernels.api.qdot_sharded`, the serve engine's wave
+sharding, the GSPMD constraints below) assumes it. Packed sub-byte arrays
+inside the context obey the invariants in `repro.parallel.sharding`:
+sharded only on the output-feature axis, never on the packed reduction
+axis (a shard boundary inside a CHUNK group would split int8 containers
+across cores).
 
 Model code calls `constrain(x, axes)` (or `constrain_first(x, options)`)
 on major intermediates; when a mesh context is active (set by the step
@@ -25,24 +26,24 @@ import contextlib
 import contextvars
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.parallel.sharding import DEFAULT_RULES, shard_spec_for
 
 _ACTIVE = contextvars.ContextVar("repro_mesh_ctx", default=None)
 
 
-def use_mesh(mesh):
-    """Ambient-mesh context manager across jax versions.
+def make_mesh(shape, axes, *, devices=None):
+    """`jax.make_mesh` with Auto axes.
 
-    Newer jax spells it `jax.set_mesh(mesh)`; on jax<=0.4 the Mesh object
-    itself is the context manager with the same ambient-mesh effect for
-    jit/shard_map spec resolution. Every repro call site (and the tests)
-    goes through this helper instead of `jax.set_mesh` directly.
+    The sharding constraints below and the `shard_map` bodies of the
+    cluster path are GSPMD-style: they need Auto mesh axes, and
+    `jax.make_mesh` builds Explicit ones unless told otherwise. Every
+    mesh of the repo (launchers, benchmarks, tests) is built here.
     """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 @contextlib.contextmanager

@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -71,12 +70,12 @@ def pipeline_apply(stage_fn, stage_params, x_micro, mesh: Mesh,
         mask = (s == n_stages - 1).astype(out.dtype)
         return jax.lax.psum(out * mask, axis)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params,
                                is_leaf=lambda x: hasattr(x, "shape")),
                   P()),
-        out_specs=P(), check_rep=False)(stage_params, x_micro)
+        out_specs=P(), check_vma=False)(stage_params, x_micro)
 
 
 def stage_stack(params_stacked, n_stages: int):

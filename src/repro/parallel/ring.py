@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -61,10 +60,10 @@ def ring_decode_attention(q, k_shard, v_shard, valid_mask, mesh: Mesh,
         return (num / jnp.maximum(den, 1e-30)[..., None]).astype(q.dtype)
 
     spec_kv = P(None, axis, None, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), spec_kv, spec_kv, P(None, axis)),
-        out_specs=P(), check_rep=False)(q, k_shard, v_shard, valid_mask)
+        out_specs=P(), check_vma=False)(q, k_shard, v_shard, valid_mask)
 
 
 def collective_matmul(x, w, mesh: Mesh, axis: str = "model"):
@@ -95,6 +94,6 @@ def collective_matmul(x, w, mesh: Mesh, axis: str = "model"):
         acc, _ = jax.lax.fori_loop(0, n, body, (acc, x_loc))
         return acc.astype(x_loc.dtype)
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(None, axis), P(None, axis)),
-                     out_specs=P(None, axis), check_rep=False)(x, w)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(None, axis), P(None, axis)),
+                         out_specs=P(None, axis), check_vma=False)(x, w)

@@ -27,6 +27,7 @@ from repro.kernels import api, tune
 from repro.kernels.qconv import quantize_conv, qconv2d_apply
 from repro.kernels.qmatmul import qlinear_apply
 from repro.nn.layers import QuantConfig, dense_apply, pack_dense_weights
+from repro.parallel.ctx import make_mesh
 
 BITS = (8, 4, 2)
 
@@ -161,8 +162,8 @@ def test_grouped_conv_rejected_under_mesh(rng):
 
     qp, xq = _mk_conv(rng, 8, 8)
     grouped = dataclasses.replace(qp, groups=2)
-    mesh = jax.make_mesh((2, 1), ("data", "model"),
-                         devices=jax.devices()[:2])
+    mesh = make_mesh((2, 1), ("data", "model"),
+                     devices=jax.devices()[:2])
     with pytest.raises((RuntimeError, ValueError),
                        match="grouped conv|no default backend supports"):
         api.qconv(grouped, xq, mesh=mesh, backend="xla")

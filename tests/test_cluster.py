@@ -18,6 +18,7 @@ import pytest
 from repro.core import packing
 from repro.core.quantize import QuantizedLinearParams
 from repro.kernels import api
+from repro.parallel.ctx import make_mesh
 from repro.parallel.sharding import (packed_conv_specs, packed_linear_specs,
                                      shard_packed_conv, shard_packed_linear)
 
@@ -30,8 +31,8 @@ needs_cluster = pytest.mark.skipif(
 
 
 def _mesh(dp, tp):
-    return jax.make_mesh((dp, tp), ("data", "model"),
-                         devices=jax.devices()[: dp * tp])
+    return make_mesh((dp, tp), ("data", "model"),
+                     devices=jax.devices()[: dp * tp])
 
 
 def _mesh_shapes():

@@ -21,13 +21,12 @@ from repro.launch.mesh import make_host_mesh
 from repro.train.step import make_train_fns, TrainStepConfig
 from repro.configs.base import ShapeConfig
 from repro.ckpt.checkpoint import save
-from repro.parallel.ctx import use_mesh
 cfg = smoke_config(); model = build(cfg)
 mesh = make_host_mesh(model=2)   # 2x2 mesh
 init_fn, step, shards = make_train_fns(model, mesh, ShapeConfig("t",16,4,"train"), TrainStepConfig())
 state = init_fn(jax.random.PRNGKey(0))
 batch = {"tokens": jnp.ones((4,16), jnp.int32), "labels": jnp.ones((4,16), jnp.int32)}
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     state, m = jax.jit(step)(state, batch)
 save(sys.argv[1], 1, state)
 print("SAVED", float(m["loss"]))
@@ -44,13 +43,12 @@ from repro.launch.mesh import make_host_mesh
 from repro.train.step import make_train_fns, TrainStepConfig
 from repro.configs.base import ShapeConfig
 from repro.ckpt.checkpoint import restore
-from repro.parallel.ctx import use_mesh
 cfg = smoke_config(); model = build(cfg)
 mesh = make_host_mesh(model=4)   # DIFFERENT mesh: 2x4
 init_fn, step, shards = make_train_fns(model, mesh, ShapeConfig("t",16,4,"train"), TrainStepConfig())
 state, s0 = restore(sys.argv[1], shardings=None)
 batch = {"tokens": jnp.ones((4,16), jnp.int32), "labels": jnp.ones((4,16), jnp.int32)}
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     state, m = jax.jit(step)(state, batch)
 print("RESTORED", s0, float(m["loss"]))
 """

@@ -7,6 +7,7 @@ import numpy as np
 
 from repro.configs.qwen2p5_3b import smoke_config
 from repro.models.api import build
+from repro.parallel.ctx import make_mesh
 from repro.serve.engine import Engine, Request
 
 
@@ -59,8 +60,8 @@ def test_engine_wave_sharding_ragged():
         pytest.skip("needs >=4 devices (XLA_FLAGS="
                     "--xla_force_host_platform_device_count=8)")
     tp = len(jax.devices()) // 4
-    mesh = jax.make_mesh((4, tp), ("data", "model"),
-                         devices=jax.devices()[: 4 * tp])
+    mesh = make_mesh((4, tp), ("data", "model"),
+                     devices=jax.devices()[: 4 * tp])
     cfg = smoke_config()
     model = build(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -91,7 +92,7 @@ def test_engine_wave_sharding_ragged():
     assert eng3.utilization_report()["devices"] == 4
     # a mesh without the dp axis serves replicated (pure-TP tolerance,
     # same as the kernel cluster path) rather than crashing mid-wave
-    tp_mesh = jax.make_mesh((2,), ("model",), devices=jax.devices()[:2])
+    tp_mesh = make_mesh((2,), ("model",), devices=jax.devices()[:2])
     eng_tp = Engine(model, params, batch_size=4, max_len=32, mesh=tp_mesh)
     got_tp = eng_tp.generate(mk())
     for g, w in zip(got_tp, want):

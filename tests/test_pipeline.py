@@ -5,17 +5,17 @@ import jax.numpy as jnp
 import pytest
 
 from repro.parallel.pipeline import pipeline_apply, stage_stack
-from repro.parallel.ctx import use_mesh
+from repro.parallel.ctx import make_mesh
 
 
 @pytest.fixture(scope="module")
 def pod_mesh():
     devs = jax.devices()
     if len(devs) % 2:
-        return jax.make_mesh((1,), ("pod",))
-    return jax.make_mesh((min(2, len(devs)),), ("pod",),
-                         devices=devs[: min(2, len(devs))]) \
-        if len(devs) >= 2 else jax.make_mesh((1,), ("pod",))
+        return make_mesh((1,), ("pod",))
+    return make_mesh((min(2, len(devs)),), ("pod",),
+                     devices=devs[: min(2, len(devs))]) \
+        if len(devs) >= 2 else make_mesh((1,), ("pod",))
 
 
 def test_pipeline_matches_serial(pod_mesh):
@@ -42,7 +42,7 @@ def test_pipeline_matches_serial(pod_mesh):
     n_micro, mb = 4, 3
     x = rng.normal(size=(n_micro, mb, d)).astype(np.float32)
     staged = stage_stack({"w": jnp.asarray(w)}, n_stages)
-    with use_mesh(pod_mesh):
+    with jax.set_mesh(pod_mesh):
         out = pipeline_apply(stage_fn, staged, jnp.asarray(x), pod_mesh)
     ref = np.stack([np.asarray(serial(jnp.asarray(x[i])))
                     for i in range(n_micro)])

@@ -6,7 +6,6 @@ import pytest
 
 from repro.launch.mesh import make_host_mesh
 from repro.parallel.ring import collective_matmul, ring_decode_attention
-from repro.parallel.ctx import use_mesh
 
 
 @pytest.fixture(scope="module")
@@ -20,7 +19,7 @@ def test_collective_matmul_matches_dense(mesh):
     M, K, N = 16, 32 * n, 24 * n
     x = rng.normal(size=(M, K)).astype(np.float32)
     w = rng.normal(size=(K, N)).astype(np.float32)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y = collective_matmul(jnp.asarray(x), jnp.asarray(w), mesh)
     np.testing.assert_allclose(np.asarray(y), x @ w, rtol=2e-4, atol=1e-4)
 
@@ -35,7 +34,7 @@ def test_ring_decode_attention_matches_dense(mesh):
     # causal-style validity: first t_valid positions per row
     t_valid = rng.integers(1, T, size=(B,))
     mask = np.arange(T)[None, :] < t_valid[:, None]
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = ring_decode_attention(jnp.asarray(q), jnp.asarray(k),
                                     jnp.asarray(v), jnp.asarray(mask), mesh)
     # dense reference
@@ -58,7 +57,7 @@ def test_ring_attention_empty_shard_safe(mesh):
     v = rng.normal(size=(B, T, H, Dh)).astype(np.float32)
     mask = np.zeros((B, T), bool)
     mask[:, :3] = True  # only the first shard sees valid keys
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = ring_decode_attention(jnp.asarray(q), jnp.asarray(k),
                                     jnp.asarray(v), jnp.asarray(mask), mesh)
     assert np.isfinite(np.asarray(out)).all()

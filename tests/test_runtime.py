@@ -32,6 +32,7 @@ if str(ROOT) not in sys.path:  # `import benchmarks` from any rootdir
 from repro.configs.qwen2p5_3b import smoke_config
 from repro.models.api import build
 from repro.obs import trace as obs
+from repro.parallel.ctx import make_mesh
 from repro.serve.runtime import (Backpressure, LMDecodeAdapter, Request,
                                  Scheduler, VisionAdapter)
 from repro.serve.runtime.slots import CapacityError, SlotManager
@@ -243,8 +244,8 @@ def test_dp_sharded_parity(lm, num_slots):
     mixed = [3, 1, 4, 2, 5]
     want = Scheduler(_adapter(lm), num_slots).serve(_reqs(5, max_new=mixed))
     tp = len(jax.devices()) // 4
-    mesh = jax.make_mesh((4, tp), ("data", "model"),
-                         devices=jax.devices()[: 4 * tp])
+    mesh = make_mesh((4, tp), ("data", "model"),
+                     devices=jax.devices()[: 4 * tp])
     sched = Scheduler(_adapter(lm, mesh=mesh), num_slots, mesh=mesh)
     got = sched.serve(_reqs(5, max_new=mixed))
     assert _outs(got) == _outs(want)

@@ -7,7 +7,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.parallel.sharding import DEFAULT_RULES, shard_spec_for
-from repro.parallel.ctx import use_mesh
 
 
 def test_rules_resolution():
@@ -66,6 +65,6 @@ def test_host_mesh_train_step_with_constraints():
     state = init_fn(jax.random.PRNGKey(0))
     batch = {"tokens": jnp.ones((2, 16), jnp.int32),
              "labels": jnp.ones((2, 16), jnp.int32)}
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state, m = jax.jit(step)(state, batch)
     assert np.isfinite(float(m["loss"]))

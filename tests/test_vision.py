@@ -26,6 +26,7 @@ from repro.core.quantize import QuantSpec, quantize, requantize_shift_i64
 from repro.deploy.calibrate import calibrate_vision
 from repro.deploy.planner import auto_budget, plan_mixed_precision
 from repro.deploy.policy import PlanRule, PrecisionPlan, load_plan, save_plan
+from repro.parallel.ctx import make_mesh
 from repro.vision import layers as vl
 from repro.vision.configs import get_vision_config
 from repro.vision.models import (collect_absmax, forward_fp, forward_int,
@@ -115,8 +116,8 @@ def test_mesh_parity(net, mixed, art):
     x5 = np.concatenate([x, x[:1]], axis=0)        # 5 % 4 != 0: pad path
     x_hat = quantize_input(qnet, x5)
     ref = np.asarray(forward_int(qnet, x_hat, backend="xla"))
-    mesh = jax.make_mesh((4, 1), ("data", "model"),
-                         devices=jax.devices()[:4])
+    mesh = make_mesh((4, 1), ("data", "model"),
+                     devices=jax.devices()[:4])
     got = np.asarray(forward_int(qnet, x_hat, backend="xla", mesh=mesh))
     assert np.array_equal(ref, got)
 
@@ -340,8 +341,8 @@ def test_vision_engine_waves_and_utilization(art):
     rng = np.random.default_rng(3)
     images = rng.uniform(0, 1, size=(6, *cfg.in_hw, cfg.in_ch)).astype(
         np.float32)
-    mesh = jax.make_mesh((2, 1), ("data", "model"),
-                         devices=jax.devices()[:2])
+    mesh = make_mesh((2, 1), ("data", "model"),
+                     devices=jax.devices()[:2])
     eng = VisionEngine(qnet, batch_size=4, mesh=mesh, backend="xla")
     got = eng.run(images)
     want = np.asarray(forward_int(
@@ -366,8 +367,8 @@ def test_vision_engine_ragged_batch_over_dp(art):
     rng = np.random.default_rng(5)
     images = rng.uniform(0, 1, size=(5, *cfg.in_hw, cfg.in_ch)).astype(
         np.float32)
-    mesh = jax.make_mesh((4, 1), ("data", "model"),
-                         devices=jax.devices()[:4])
+    mesh = make_mesh((4, 1), ("data", "model"),
+                     devices=jax.devices()[:4])
     eng = VisionEngine(qnet, batch_size=3, mesh=mesh, backend="xla")
     got = eng.run(images)
     want = np.asarray(forward_int(
