@@ -43,9 +43,11 @@ differential tests can force one mode suite-wide.
 records one structured dispatch event — requested backend/pipeline, plan
 hint, env override, tune-cache hit/miss and winner, final choice with
 per-field provenance — queryable via `repro.obs.dispatch_log()`, and
-every entry-point call bumps the per-(op, bits, backend, pipeline)
-MAC/byte counters and runs inside a ``cat='kernel'`` span. Disabled
-(the default), the instrumentation is a single predicate per call.
+every eager entry-point call bumps the per-(op, bits, backend, pipeline)
+MAC/byte counters and runs inside a ``cat='kernel'`` span. A call under
+a `jit` trace records neither: there it would time the trace and count
+once per compilation. Disabled (the default), the instrumentation is a
+single predicate per call.
 
 **Cluster-parallel path (paper fig. 9).** Passing ``mesh=`` to
 `qdot`/`qconv` (or calling `qdot_sharded`/`qconv_sharded` directly) runs
@@ -313,15 +315,24 @@ def _resolve_call(op: str, shape, a_bits: int, w_bits: int, *,
     return spec, block, pipeline
 
 
+def _traced(tree) -> bool:
+    """True when any leaf of ``tree`` is a tracer: the call is being
+    staged into a `jit` (or other) trace, not run."""
+    return any(isinstance(v, jax.core.Tracer)
+               for v in jax.tree_util.tree_leaves(tree))
+
+
 def _run_counted(spec, op: str, shape, a_bits: int, w_bits: int,
-                 pipeline: str, thunk, w_packed_bytes: Optional[int] = None):
-    """Run the resolved backend. With observability on, bump the
-    (op, bits, backend, pipeline) MAC/byte counters and wrap the run in
-    a ``cat='kernel'`` span that blocks on the result so device time
-    lands inside it; off, it's a bare call. ``w_packed_bytes`` overrides
-    the uniform-container weight-byte estimate (segmented containers
-    stream fewer bytes than a uniform buffer at the widest width)."""
-    if not obs.enabled():
+                 pipeline: str, thunk, operands=(),
+                 w_packed_bytes: Optional[int] = None):
+    """Run the resolved backend. With observability on and the call
+    eager, bump the (op, bits, backend, pipeline) MAC/byte counters and
+    wrap the run in a ``cat='kernel'`` span that blocks on the result so
+    device time lands inside it; off, or with any of ``operands`` a
+    tracer, it's a bare call. ``w_packed_bytes`` overrides the
+    uniform-container weight-byte estimate (segmented containers stream
+    fewer bytes than a uniform buffer at the widest width)."""
+    if not obs.enabled() or _traced(operands):
         return thunk()
     costs = obs_counters.record(op, shape, a_bits, w_bits,
                                 backend=spec.name, pipeline=pipeline,
@@ -396,6 +407,7 @@ def qdot_packed(params, x_packed, *, epilogue: str = "int", scale=1.0,
             spec, "qdot_mixed", (m, k, n), params.a_bits, w_key, pipeline,
             lambda: spec.run(params, x_packed, epilogue=epilogue,
                              scale=scale, block=block, pipeline=pipeline),
+            operands=(params, x_packed),
             w_packed_bytes=params.segmap.packed_bytes(params.k_logical))
     m = x_packed.shape[0]
     k = x_packed.shape[1] * packing.pack_factor(params.a_bits)
@@ -406,7 +418,8 @@ def qdot_packed(params, x_packed, *, epilogue: str = "int", scale=1.0,
     return _run_counted(
         spec, "qdot", (m, k, n), params.a_bits, params.w_bits, pipeline,
         lambda: spec.run(params, x_packed, epilogue=epilogue, scale=scale,
-                         block=block, pipeline=pipeline))
+                         block=block, pipeline=pipeline),
+        operands=(params, x_packed))
 
 
 # ----------------------------------------------------------- qconv entry ---
@@ -467,7 +480,8 @@ def qconv(params, x_hat, *, epilogue: str = "int", scale=1.0,
     return _run_counted(
         spec, "qconv", shape, g.a_bits, g.w_bits, pipeline,
         lambda: spec.run(params, x_hat, epilogue=epilogue, scale=scale,
-                         block=block, pipeline=pipeline))
+                         block=block, pipeline=pipeline),
+        operands=(params, x_hat))
 
 
 # ------------------------------------------------ cluster-parallel path ---
@@ -545,7 +559,8 @@ def qdot_sharded(params, x_hat, *, mesh, dp_axis: str = "data",
                       wspecs["lam"], wspecs["m"],
                       P(tpe) if per_n else P()),
             out_specs=P(dpe, tpe), check_vma=False)(
-            x2, params.w_packed, params.kappa, params.lam, params.m, sc))
+            x2, params.w_packed, params.kappa, params.lam, params.m, sc),
+        operands=(params, x2))
     return out[:m].reshape(*lead, n)
 
 
@@ -602,7 +617,8 @@ def qconv_sharded(params, x_hat, *, mesh, dp_axis: str = "data",
                       wspecs["gemm"]["lam"], wspecs["gemm"]["m"],
                       P(tpe) if per_n else P()),
             out_specs=P(dpe, None, None, tpe), check_vma=False)(
-            x, params.w_packed_fused, g.w_packed, g.kappa, g.lam, g.m, sc))
+            x, params.w_packed_fused, g.w_packed, g.kappa, g.lam, g.m, sc),
+        operands=(params, x))
     return out[:nb]
 
 

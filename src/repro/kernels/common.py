@@ -287,7 +287,7 @@ def conv_default_block(n, ho, wo, cout, fh, fw, cin_pad, stride,
     while not fits(bho, bn) and bho > 1:
         bho = max(1, bho // 2)
     while not fits(bho, bn) and bn > LANE:
-        bn //= 2
+        bn = max(LANE, bn // 2 // LANE * LANE)     # stays a LANE multiple
     if not fits(bho, bn):
         raise ValueError(
             f"fused conv tile (bho=1, bn={LANE}) exceeds the VMEM budget "

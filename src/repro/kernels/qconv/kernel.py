@@ -261,6 +261,7 @@ def qconv2d_fused(x_hat, w_packed_fused, kappa, lam, m_mul, *,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
+            name="qconv_fused_db",
         )(xp, wpk, kappa2, lam2, mm2)
         return out[:, :ho, :, :cout]
 
@@ -287,5 +288,6 @@ def qconv2d_fused(x_hat, w_packed_fused, kappa, lam, m_mul, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="qconv_fused",
     )(xp, wpk, kappa2, lam2, mm2)
     return out[:, :ho, :, :cout]

@@ -123,9 +123,6 @@ def qconv2d_apply(params: QuantizedConvParams, x_hat, *,
     `api.resolve_legacy_backend`.
     """
     from repro.kernels import api
-    from repro.obs import trace as obs
 
     backend = api.resolve_legacy_backend(backend, use_kernel, interpret)
-    with obs.span("qconv2d_apply", cat="compat",
-                  legacy=use_kernel is not None or interpret is not None):
-        return api.qconv(params, x_hat, backend=backend, block=block)
+    return api.qconv(params, x_hat, backend=backend, block=block)

@@ -198,6 +198,7 @@ def qmatmul_packed(x, w_packed, kappa, lam, m_mul, *,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
+            name="qmatmul_db",
         )(x, w_packed, kappa.reshape(1, -1), lam.reshape(1, -1),
           m_mul.reshape(1, -1))
 
@@ -222,6 +223,7 @@ def qmatmul_packed(x, w_packed, kappa, lam, m_mul, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="qmatmul",
     )(x, w_packed, kappa.reshape(1, -1), lam.reshape(1, -1),
       m_mul.reshape(1, -1))
 
@@ -383,6 +385,7 @@ def qmatmul_segmented(x, w_flat, segmap, kappa, lam, m_mul, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="qmatmul_segmented",
     )(jnp.asarray(codes, jnp.int32), jnp.asarray(offs // LANE, jnp.int32),
       x, kappa.reshape(1, -1), lam.reshape(1, -1), m_mul.reshape(1, -1),
       w_flat.reshape(-1, LANE))

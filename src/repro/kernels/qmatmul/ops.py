@@ -19,7 +19,6 @@ from typing import Optional
 from repro.core import packing
 from repro.core.quantize import QuantizedLinearParams
 from repro.kernels import api
-from repro.obs import trace as obs
 
 
 def qmatmul_jnp(x_packed, w_packed, kappa, lam, m_mul, *,
@@ -44,10 +43,8 @@ def qlinear_apply(params: QuantizedLinearParams, x_hat, *,
     directly. ``use_kernel``/``interpret`` are deprecated aliases.
     """
     backend = api.resolve_legacy_backend(backend, use_kernel, interpret)
-    with obs.span("qlinear_apply", cat="compat",
-                  legacy=use_kernel is not None or interpret is not None):
-        return api.qdot(params, x_hat, epilogue=epilogue, scale=scale,
-                        backend=backend, block=block)
+    return api.qdot(params, x_hat, epilogue=epilogue, scale=scale,
+                    backend=backend, block=block)
 
 
 def qlinear_apply_packed(params: QuantizedLinearParams, x_packed, *,
@@ -59,7 +56,5 @@ def qlinear_apply_packed(params: QuantizedLinearParams, x_packed, *,
     """`qlinear_apply` over already-packed activations (compat wrapper over
     `repro.kernels.api.qdot_packed`)."""
     backend = api.resolve_legacy_backend(backend, use_kernel, interpret)
-    with obs.span("qlinear_apply_packed", cat="compat",
-                  legacy=use_kernel is not None or interpret is not None):
-        return api.qdot_packed(params, x_packed, epilogue=epilogue,
-                               scale=scale, backend=backend, block=block)
+    return api.qdot_packed(params, x_packed, epilogue=epilogue,
+                           scale=scale, backend=backend, block=block)

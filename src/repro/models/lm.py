@@ -244,7 +244,8 @@ def decode_step(params, cache, token, index, cfg: ModelConfig, *,
 
     For vision archs the cross K/V are recomputed from src_embed on step 0
     and cached (prefill fills them in practice; dry-run lowers this path).
-    Returns (logits (B,1,V), new_cache).
+    Returns (logits (B,1,V), new_cache). The named scopes ``attn``,
+    ``mlp`` (per scanned layer) and ``head`` label the device ops.
     """
     dtype = jnp.bfloat16 if cfg.compute_dtype == "bfloat16" else jnp.float32
     b = token.shape[0]
@@ -263,17 +264,20 @@ def decode_step(params, cache, token, index, cfg: ModelConfig, *,
         def body(x, per_layer):
             lp, kv_l, w_l, r_l = per_layer
             th = jnp.where(r_l == 1, th_l, th_g)
-            h, new_kv = attn_decode(
-                lp["attn"], norm_apply(lp.get("ln1", {}), x, cfg.norm), kv_l, index,
-                acfg, theta=th, mode="local", window=w_l)
+            with jax.named_scope("attn"):
+                h, new_kv = attn_decode(
+                    lp["attn"], norm_apply(lp.get("ln1", {}), x, cfg.norm),
+                    kv_l, index, acfg, theta=th, mode="local", window=w_l)
             x = x + h
-            if cfg.moe is not None:
-                h, _ = moe_apply(lp["moe"],
-                                 norm_apply(lp.get("ln2", {}), x, cfg.norm),
-                                 _moe_cfg(cfg))
-            else:
-                h = mlp_apply(lp["mlp"], norm_apply(lp.get("ln2", {}), x, cfg.norm),
-                              _mlp_cfg(cfg))
+            with jax.named_scope("mlp"):
+                if cfg.moe is not None:
+                    h, _ = moe_apply(lp["moe"],
+                                     norm_apply(lp.get("ln2", {}), x, cfg.norm),
+                                     _moe_cfg(cfg))
+                else:
+                    h = mlp_apply(lp["mlp"],
+                                  norm_apply(lp.get("ln2", {}), x, cfg.norm),
+                                  _mlp_cfg(cfg))
             return x + h, new_kv
 
         x, new_kv = jax.lax.scan(body, x, (params["layers"],
@@ -296,12 +300,15 @@ def decode_step(params, cache, token, index, cfg: ModelConfig, *,
             def inner(x2, pl2):
                 lp, kv_l, w_l, r_l = pl2
                 th = jnp.where(r_l == 1, th_l, th_g)
-                h, nkv = attn_decode(
-                    lp["attn"], norm_apply(lp.get("ln1", {}), x2, cfg.norm), kv_l,
-                    index, acfg, theta=th, mode="local", window=w_l)
+                with jax.named_scope("attn"):
+                    h, nkv = attn_decode(
+                        lp["attn"], norm_apply(lp.get("ln1", {}), x2, cfg.norm),
+                        kv_l, index, acfg, theta=th, mode="local", window=w_l)
                 x2 = x2 + h
-                h = mlp_apply(lp["mlp"], norm_apply(lp.get("ln2", {}), x2, cfg.norm),
-                              _mlp_cfg(cfg))
+                with jax.named_scope("mlp"):
+                    h = mlp_apply(lp["mlp"],
+                                  norm_apply(lp.get("ln2", {}), x2, cfg.norm),
+                                  _mlp_cfg(cfg))
                 return x2 + h, nkv
 
             x, nkvg = jax.lax.scan(inner, x, (gp, kvg, w_g, r_g))
@@ -321,5 +328,6 @@ def decode_step(params, cache, token, index, cfg: ModelConfig, *,
             lambda a: a.reshape(n_self, *a.shape[2:]), new_kvg)
         new_cache = dict(cache, kv=new_kv)
 
-    x = norm_apply(params.get("final_norm", {}), x, cfg.norm)
-    return _logits(params, x, cfg), new_cache
+    with jax.named_scope("head"):
+        x = norm_apply(params.get("final_norm", {}), x, cfg.norm)
+        return _logits(params, x, cfg), new_cache

@@ -14,11 +14,12 @@ env knobs before jax initialises.
 """
 from repro.obs import env  # noqa: F401
 from repro.obs.trace import (TRACE_SCHEMA_VERSION, chrome_trace,  # noqa: F401
-                             counter, counter_values, disable,
-                             dispatch_event, dispatch_log, enable, enabled,
-                             enabled_scope, events, export_chrome_trace,
-                             export_if_configured, span, spans, summary,
-                             time_call)
+                             complete, counter, counter_values, disable,
+                             dispatch_event, dispatch_log, dropped, enable,
+                             enabled, enabled_scope, events,
+                             export_chrome_trace, export_if_configured,
+                             now_us, span, spans, summary, time_call,
+                             to_perf_counter)
 
 
 def reset() -> None:

@@ -22,9 +22,9 @@ accumulates
 ``logical/packed`` per bucket is the measured container-compression
 ratio; ``macs/packed_bytes`` is the arithmetic intensity the fig8
 roofline plots. Recording is a no-op unless `repro.obs.trace` is
-enabled. Under `jax.jit` the entry points run once per *trace*, so
-counters record per compilation there — the instrumented benchmarks and
-the serve engines call the registry un-jitted, where counts are
+enabled. Under `jax.jit` the entry points run once per *trace*, not per
+call, so `repro.kernels.api` records nothing for a staged call; the
+instrumented benchmarks call the registry un-jitted, where counts are
 per-call.
 """
 from __future__ import annotations

@@ -22,10 +22,16 @@ Design points:
   under a top-level ``"repro"`` key, which the format explicitly allows.
 * **jax-aware, jax-free.** jax is imported lazily inside `time_call` /
   `Span.sync` only, so this module (and `repro.obs.env`) can load
-  before jax initialises. `jax.block_until_ready` is tracer-safe, so
-  spans may wrap code under `jit` tracing — such a span measures *trace*
-  time and fires once per compilation, which is exactly when the op
-  counters record too (documented in docs/architecture.md).
+  before jax initialises. A span around code under `jit` tracing would
+  time the trace, once per compilation: the kernel entry points open
+  none there (`kernels/api.py::_run_counted`).
+* **One clock with the profiler.** With ``xla_annotations`` on, every
+  span is also a `jax.profiler.TraceAnnotation`, so a profiled run shows
+  the spans on the host plane of the same ``.xplane.pb`` as the device
+  ops. `to_perf_counter` maps a span's ``ts`` onto
+  `time.perf_counter()` seconds, the clock callers time windows with.
+* **Loss is counted.** `dropped()` counts the events the ring buffer
+  pushed out, so a reader can refuse a window that lost spans.
 
 Timestamps are microseconds relative to a module-load epoch
 (`perf_counter_ns`), matching the trace-event format's ``ts``/``dur``
@@ -53,10 +59,27 @@ _COUNTERS: Dict[str, "Counter"] = {}
 _TIDS: Dict[int, int] = {}
 _ENABLED = obsenv.get_bool("REPRO_OBS")
 _XLA_ANNOTATIONS = False
+_DROPPED = 0
 
 
-def _now_us() -> float:
+def now_us() -> float:
+    """The current time on the spans' clock (µs since the epoch)."""
     return (time.perf_counter_ns() - _T0_NS) / 1e3
+
+
+def to_perf_counter(ts_us: float) -> float:
+    """A span's ``ts`` (or ``ts + dur``) as `time.perf_counter()`
+    seconds."""
+    return (_T0_NS + ts_us * 1e3) * 1e-9
+
+
+def _append(event: Dict[str, Any]) -> None:
+    """Add one event to the ring buffer (caller holds ``_LOCK``),
+    counting the oldest one it pushes out."""
+    global _DROPPED
+    if len(_EVENTS) == _EVENTS.maxlen:
+        _DROPPED += 1
+    _EVENTS.append(event)
 
 
 def _tid() -> int:
@@ -79,9 +102,10 @@ def enable(capacity: Optional[int] = None,
            xla_annotations: Optional[bool] = None) -> None:
     """Turn observability on; optionally resize the ring buffers and/or
     mirror spans into XLA profiles via `jax.profiler.TraceAnnotation`."""
-    global _ENABLED, _EVENTS, _DISPATCH, _XLA_ANNOTATIONS
+    global _ENABLED, _EVENTS, _DISPATCH, _XLA_ANNOTATIONS, _DROPPED
     with _LOCK:
         if capacity is not None and capacity != _EVENTS.maxlen:
+            _DROPPED += max(0, len(_EVENTS) - capacity)
             _EVENTS = deque(_EVENTS, maxlen=capacity)
             _DISPATCH = deque(_DISPATCH, maxlen=capacity)
         if xla_annotations is not None:
@@ -97,8 +121,10 @@ def disable() -> None:
 def reset() -> None:
     """Drop all recorded events, dispatch entries, and generic counters
     (op counters live in `repro.obs.counters` — `repro.obs.reset()`
-    clears both)."""
+    clears both), and zero the drop count."""
+    global _DROPPED
     with _LOCK:
+        _DROPPED = 0
         _EVENTS.clear()
         _DISPATCH.clear()
         _COUNTERS.clear()
@@ -136,6 +162,9 @@ class Span:
         self._ann = None
 
     def __enter__(self) -> "Span":
+        # the span's interval includes its profiler annotation: in a
+        # profiled run that cost is host time spent in the region
+        self._t0 = now_us()
         if _XLA_ANNOTATIONS:
             try:
                 import jax
@@ -143,7 +172,6 @@ class Span:
                 self._ann.__enter__()
             except Exception:
                 self._ann = None
-        self._t0 = _now_us()
         return self
 
     def set(self, **attrs) -> "Span":
@@ -159,14 +187,14 @@ class Span:
         return value
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        dur = _now_us() - self._t0
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
+        dur = now_us() - self._t0
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
         if _ENABLED:
             with _LOCK:
-                _EVENTS.append({
+                _append({
                     "name": self.name, "cat": self.cat, "ph": "X",
                     "ts": round(self._t0, 3), "dur": round(dur, 3),
                     "pid": 0, "tid": _tid(),
@@ -201,6 +229,25 @@ def span(name: str, cat: str = "span", **attrs):
     if not _ENABLED:
         return _NULL_SPAN
     return Span(name, cat, attrs)
+
+
+def complete(name: str, start_us: float, cat: str = "span",
+             **attrs) -> None:
+    """Record a span that began at ``start_us`` (a `now_us()` reading)
+    and ends now: for an interval no one block encloses, such as a
+    request's wait in a queue. A no-op when disabled."""
+    if not _ENABLED:
+        return
+    end = now_us()
+    with _LOCK:
+        _append({"name": name, "cat": cat, "ph": "X",
+                 "ts": round(start_us, 3), "dur": round(end - start_us, 3),
+                 "pid": 0, "tid": _tid(), "args": dict(attrs)})
+
+
+def dropped() -> int:
+    """Events the ring buffer pushed out since the last `reset()`."""
+    return _DROPPED
 
 
 # --------------------------------------------------------------- counters ---
@@ -258,10 +305,10 @@ def dispatch_event(**fields) -> None:
     decision inline with the kernel spans."""
     if not _ENABLED:
         return
-    ts = _now_us()
+    ts = now_us()
     with _LOCK:
         _DISPATCH.append(dict(fields, ts=round(ts, 3)))
-        _EVENTS.append({
+        _append({
             "name": f"dispatch:{fields.get('op', '?')}",
             "cat": "dispatch", "ph": "i", "s": "t",
             "ts": round(ts, 3), "pid": 0, "tid": _tid(),

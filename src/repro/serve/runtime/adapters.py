@@ -19,6 +19,15 @@ and what one engine step computes). An adapter provides:
   ``feed``/``consume`` drive it one step at a time, and ``consume``'s
   return value is the **finished predicate** (mid-wave eviction point).
 
+With `repro.obs` on, the LM and vision steps split into three spans
+each (``lm.*`` / ``vision.*``): ``dispatch`` (inputs to the device, the
+jitted call and, for the LM, the eager slice of the last position's
+logits returning), ``device_wait`` (blocking on the step's outputs) and
+``logits_to_host`` (the copy to host memory); the LM also counts
+``lm.bytes_to_host``. Off, the step does what it did unspanned: the
+copy itself blocks on the program. On, the device runs the same work in
+the same order: everything is queued before the wait.
+
 Per-request bit-exactness invariant: every adapter's step must be
 row-independent (slot *i*'s outputs depend only on slot *i*'s feeds),
 which is what makes continuous batching bit-exact vs synchronous waves
@@ -31,6 +40,8 @@ import dataclasses
 from typing import Any, List, Optional, Tuple
 
 import numpy as np
+
+from repro.obs import trace as obs
 
 # per-slot carried state that must be cleared on slot reuse, keyed by the
 # cache subtree name: leaves are (layers, slots, ...) with zero init
@@ -218,10 +229,17 @@ class LMDecodeAdapter(WorkloadAdapter):
     def step(self, cache, feed, positions):
         import jax.numpy as jnp
 
-        logits, cache = self._decode(
-            self.params, cache, self._put_wave(feed),
-            self._put_wave(positions.astype(np.int32)))
-        rows = np.asarray(logits[:, -1].astype(jnp.float32))  # (B, V)
+        with obs.span("lm.dispatch", cat="lm"):
+            logits, cache = self._decode(
+                self.params, cache, self._put_wave(feed),
+                self._put_wave(positions.astype(np.int32)))
+            # queued behind the step before any wait, as with spans off
+            last = logits[:, -1].astype(jnp.float32)             # (B, V)
+        with obs.span("lm.device_wait", cat="lm") as sp:
+            sp.sync((last, cache))
+        with obs.span("lm.logits_to_host", cat="lm"):
+            rows = np.asarray(last)
+        obs.counter("lm.bytes_to_host").add(rows.nbytes)
         return rows, cache
 
     # ---- request cursor ----
@@ -317,8 +335,11 @@ class VisionAdapter(WorkloadAdapter):
         self.mesh = mesh
         self.dp_axis = dp_axis
         self.backend = backend
-        self._forward = jax.jit(
-            lambda xh: forward_int(qnet, xh, backend=backend, mesh=mesh))
+
+        def vision_forward(xh):
+            return forward_int(qnet, xh, backend=backend, mesh=mesh)
+
+        self._forward = jax.jit(vision_forward)
         self._spec = ((*qnet.cfg.in_hw, qnet.cfg.in_ch), np.int8)
 
     def input_spec(self):
@@ -327,8 +348,13 @@ class VisionAdapter(WorkloadAdapter):
     def step(self, state, feed, positions):
         import jax.numpy as jnp
 
-        logits = self._forward(jnp.asarray(feed))
-        return np.asarray(logits), state
+        with obs.span("vision.dispatch", cat="vision"):
+            logits = self._forward(jnp.asarray(feed))
+        with obs.span("vision.device_wait", cat="vision") as sp:
+            sp.sync(logits)
+        with obs.span("vision.logits_to_host", cat="vision"):
+            rows = np.asarray(logits)
+        return rows, state
 
     def begin(self, payload, *, rid: int, greedy: bool = True,
               seed: int = 0):

@@ -22,6 +22,16 @@ Because every adapter step is row-independent and sampling is keyed per
 request, per-request outputs are **bit-exact across policies, admission
 orders, and slot placements** — continuous batching changes *when* a
 request runs, never *what* it computes.
+
+With `repro.obs` on, each phase records a ``cat="serve"`` span:
+``serve.submit`` (arg ``rid``: the adapter's ``begin`` and the fit
+check), and per step ``serve.admit`` (admission, ``reset_state``, the
+active set), ``serve.feed`` (the feed rows), ``serve.step`` (the
+adapter's step) and ``serve.consume`` (taking the new state, which
+releases the old; consume and evict; the step record), so that the
+scheduler's own host time is the step minus ``serve.step``.
+``serve.queue`` (arg ``rid``) spans a request's wait from submit to
+admission, on the spans' clock.
 """
 from __future__ import annotations
 
@@ -120,6 +130,7 @@ class _Entry:
     admit_t: Optional[float] = None
     finish_t: Optional[float] = None
     sid: Optional[int] = None
+    submit_us: Optional[float] = None    # spans' clock, when obs is on
 
 
 class Scheduler(WaveStats):
@@ -184,10 +195,12 @@ class Scheduler(WaveStats):
         now = self.clock() if now is None else now
         rid = self._next_rid
         self._next_rid += 1
-        cur = self.adapter.begin(payload, rid=rid - self._rid0,
-                                 greedy=self._greedy, seed=self._seed)
-        self.slots.check_fits(self.adapter.prompt_len(cur))
-        e = _Entry(rid=rid, cursor=cur, submit_t=now)
+        with obs.span("serve.submit", cat="serve", rid=rid):
+            cur = self.adapter.begin(payload, rid=rid - self._rid0,
+                                     greedy=self._greedy, seed=self._seed)
+            self.slots.check_fits(self.adapter.prompt_len(cur))
+        e = _Entry(rid=rid, cursor=cur, submit_t=now,
+                   submit_us=obs.now_us() if obs.enabled() else None)
         self._entries[rid] = e
         if getattr(cur, "done", False):
             # degenerate request (e.g. max_new_tokens == 0): completes
@@ -221,6 +234,8 @@ class Scheduler(WaveStats):
         e.sid = self.slots.admit(
             e.rid, self.adapter.reserve_tokens(e.cursor))
         e.admit_t = now
+        if e.submit_us is not None:
+            obs.complete("serve.queue", e.submit_us, cat="serve", rid=e.rid)
         return e
 
     # ------------------------------------------------------ event loop ---
@@ -231,32 +246,36 @@ class Scheduler(WaveStats):
         with nothing admitted and nothing active is a no-op (drain on an
         empty queue is safe)."""
         now = self.clock() if now is None else now
-        self._admit(now)
-        active = self.slots.active
+        with obs.span("serve.admit", cat="serve"):
+            self._admit(now)
+            active = self.slots.active
         if not active:
             return []
-        shape, dtype = self.adapter.input_spec()
-        feed = np.zeros((self.slots.phys, *shape), dtype)
-        pos = np.zeros(self.slots.phys, np.int32)
-        for s in active:
-            row, p = self.adapter.feed(self._entries[s.rid].cursor)
-            feed[s.sid] = row
-            pos[s.sid] = p
+        with obs.span("serve.feed", cat="serve", active=len(active)):
+            shape, dtype = self.adapter.input_spec()
+            feed = np.zeros((self.slots.phys, *shape), dtype)
+            pos = np.zeros(self.slots.phys, np.int32)
+            for s in active:
+                row, p = self.adapter.feed(self._entries[s.rid].cursor)
+                feed[s.sid] = row
+                pos[s.sid] = p
         with obs.span("serve.step", cat="serve", active=len(active),
                       queue_depth=len(self._queue)):
-            rows, self.state = self.adapter.step(self.state, feed, pos)
+            rows, state = self.adapter.step(self.state, feed, pos)
         finished: List[int] = []
-        for s in active:
-            e = self._entries[s.rid]
-            self.slots.advance(s.sid, int(pos[s.sid]) + 1)
-            if self.adapter.consume(e.cursor, rows[s.sid]):
-                self._finish(e, now)
-                finished.append(e.rid)
-        self.step_log.append({
-            "t": now, "active": len(active),
-            "queue_depth": len(self._queue),
-            "occupancy": self.slots.occupancy(),
-            "per_device": self.slots.device_occupancy()})
+        with obs.span("serve.consume", cat="serve"):
+            self.state = state          # releases the previous step's state
+            for s in active:
+                e = self._entries[s.rid]
+                self.slots.advance(s.sid, int(pos[s.sid]) + 1)
+                if self.adapter.consume(e.cursor, rows[s.sid]):
+                    self._finish(e, now)
+                    finished.append(e.rid)
+            self.step_log.append({
+                "t": now, "active": len(active),
+                "queue_depth": len(self._queue),
+                "occupancy": self.slots.occupancy(),
+                "per_device": self.slots.device_occupancy()})
         return finished
 
     def _finish(self, e: _Entry, now: float):

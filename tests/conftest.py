@@ -9,6 +9,9 @@
 * ``slow`` marker — long-running tests (CLI subprocess smokes, many-arch
   sweeps) are deselected by default so tier-1 stays fast; run them with
   ``pytest --runslow``.
+* ``repro.obs`` on/off state is restored after every test: the chip
+  benchmark's span readers turn spans on when they are loaded, and a
+  test that loads them must not leave spans on for the tests after it.
 * ``hypothesis_api()`` — guarded import of hypothesis so collection never
   hard-fails when it is not installed: property tests degrade to
   individually-skipped tests instead of breaking the whole module
@@ -29,6 +32,14 @@ import pytest
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture(autouse=True)
+def _restore_obs_state():
+    from repro.obs import trace
+    state = (trace._ENABLED, trace._XLA_ANNOTATIONS)
+    yield
+    trace._ENABLED, trace._XLA_ANNOTATIONS = state
 
 
 def pytest_addoption(parser):

@@ -88,8 +88,17 @@ def _check_conv_block(ho, wo, cout, fh, fw, cin_pad, stride, a_bits, w_bits):
 @settings(max_examples=100, deadline=None)
 def test_conv_default_block_fits_vmem(ho, wo, cout, fh, fw, n_chunks,
                                       stride, a_bits, w_bits):
-    _check_conv_block(ho, wo, cout, fh, fw, n_chunks * packing.CHUNK,
-                      stride, a_bits, w_bits)
+    cin_pad = n_chunks * packing.CHUNK
+    try:
+        _check_conv_block(ho, wo, cout, fh, fw, cin_pad, stride, a_bits,
+                          w_bits)
+    except ValueError:
+        # the selector refuses (im2col fallback) only an image whose
+        # smallest tile, one row by one lane group, busts the budget
+        assert conv_working_set(
+            1, LANE, ho=ho, wo=wo, cout=cout, fh=fh, fw=fw,
+            cin_pad=cin_pad, stride=stride, a_bits=a_bits,
+            w_bits=w_bits) > BUDGET
 
 
 # deterministic edge cases — these run even without hypothesis installed
@@ -101,6 +110,17 @@ def test_conv_block_ragged_edges(ho, wo, stride):
                                 cin_pad=packing.CHUNK, stride=stride,
                                 a_bits=4, w_bits=4)
     assert -(-ho // bho) * bho >= ho
+
+
+@pytest.mark.parametrize("stride", [1, 2])
+def test_conv_block_halves_bn_in_lane_steps(stride):
+    """Regression (a Hypothesis find): a 5x7 filter over 3 chunks with
+    cout 257 starts at bn=384 and must shrink it; halving to 192 broke
+    the LANE-multiple invariant, shrinking in LANE steps keeps it."""
+    bho, bn = _check_conv_block(1, 1, cout=257, fh=5, fw=7,
+                                cin_pad=3 * packing.CHUNK, stride=stride,
+                                a_bits=8, w_bits=8)
+    assert bn < 4 * LANE
 
 
 def test_conv_block_paper_layers():
