@@ -20,13 +20,15 @@ and what one engine step computes). An adapter provides:
   return value is the **finished predicate** (mid-wave eviction point).
 
 With `repro.obs` on, the LM and vision steps split into three spans
-each (``lm.*`` / ``vision.*``): ``dispatch`` (inputs to the device, the
-jitted call and, for the LM, the eager slice of the last position's
-logits returning), ``device_wait`` (blocking on the step's outputs) and
-``logits_to_host`` (the copy to host memory); the LM also counts
-``lm.bytes_to_host``. Off, the step does what it did unspanned: the
-copy itself blocks on the program. On, the device runs the same work in
-the same order: everything is queued before the wait.
+each (``lm.*`` / ``vision.*``): ``dispatch`` (inputs to the device and
+the jitted call returning), ``device_wait`` (blocking on the step's
+outputs) and ``logits_to_host`` (the copy of the step's host outputs:
+for the LM, one greedy int32 token per slot, or the last position's
+full logits in a step that feeds a sampled request). The LM also counts
+``lm.bytes_to_host`` and ``lm.host_sample_steps``, the steps that
+copied full logits. Off, the step does what it did unspanned: the copy
+itself blocks on the program. On, the device runs the same work in the
+same order: everything is queued before the wait.
 
 Per-request bit-exactness invariant: every adapter's step must be
 row-independent (slot *i*'s outputs depend only on slot *i*'s feeds),
@@ -139,10 +141,11 @@ class _LMCursor:
 class LMDecodeAdapter(WorkloadAdapter):
     """Token-synchronous LM decode over the Model API.
 
-    Prefill and decode are the same jitted ``model.decode`` call with a
-    per-slot position vector: a slot working through its prompt is fed
-    prompt tokens (outputs ignored until the last prompt position — the
-    wave engine's replay-prefill, now per slot), then generated tokens.
+    Prefill and decode are the same jitted ``decode`` call
+    (``model.decode`` and the greedy pick below) with a per-slot position
+    vector: a slot working through its prompt is fed prompt tokens
+    (outputs ignored until the last prompt position — the wave engine's
+    replay-prefill, now per slot), then generated tokens.
     An all-equal position vector is bit-exact vs the scalar-index wave
     path, so per-request outputs are identical to `Engine.generate`'s.
 
@@ -152,6 +155,13 @@ class LMDecodeAdapter(WorkloadAdapter):
     and no earlier EOS; the EOS token itself is emitted (wave parity).
     Non-greedy sampling draws from a per-request generator seeded
     ``(seed, rid)`` so outputs stay admission-order invariant.
+
+    The jitted step picks each slot's greedy token on the device (the
+    lowest index among the largest logits, as ``np.argmax`` picks), so a
+    step copies one int32 per slot to the host. A step that feeds a
+    sampled cursor (``feed`` marks it) copies the last position's full
+    logits instead, and its greedy cursors take the argmax of their row:
+    the same token.
     """
 
     name = "lm"
@@ -159,6 +169,7 @@ class LMDecodeAdapter(WorkloadAdapter):
     def __init__(self, model, params, max_len: int, *, eos_id: int = 1,
                  mesh=None, dp_axis: str = "data", plan=None):
         import jax
+        import jax.numpy as jnp
 
         self.model = model
         self.max_len = max_len
@@ -170,7 +181,14 @@ class LMDecodeAdapter(WorkloadAdapter):
             from jax.sharding import NamedSharding, PartitionSpec as P
             params = jax.device_put(params, NamedSharding(mesh, P()))
         self.params = params
-        self._decode = jax.jit(model.decode)
+        self._host_rows = False     # a sampled cursor was fed this step
+
+        def decode(params, cache, token, index):
+            logits, cache = model.decode(params, cache, token, index)
+            last = logits[:, -1]                                 # (B, V)
+            return jnp.argmax(last, -1).astype(jnp.int32), last, cache
+
+        self._decode = jax.jit(decode)
 
     # ---- placement (same layout as the wave engine) ----
 
@@ -227,20 +245,24 @@ class LMDecodeAdapter(WorkloadAdapter):
         return ((1,), np.int32)
 
     def step(self, cache, feed, positions):
-        import jax.numpy as jnp
-
+        """-> (rows, cache). A row is the slot's greedy token, shaped
+        like its feed row ((B, 1) int32), or, in a step that fed a
+        sampled cursor, the last position's logits ((B, V) float32)."""
         with obs.span("lm.dispatch", cat="lm"):
-            logits, cache = self._decode(
+            tokens, last, cache = self._decode(
                 self.params, cache, self._put_wave(feed),
                 self._put_wave(positions.astype(np.int32)))
-            # queued behind the step before any wait, as with spans off
-            last = logits[:, -1].astype(jnp.float32)             # (B, V)
+        host_rows, self._host_rows = self._host_rows, False
+        out = last if host_rows else tokens
         with obs.span("lm.device_wait", cat="lm") as sp:
-            sp.sync((last, cache))
+            sp.sync((out, cache))
         with obs.span("lm.logits_to_host", cat="lm"):
-            rows = np.asarray(last)
+            rows = np.asarray(out)
         obs.counter("lm.bytes_to_host").add(rows.nbytes)
-        return rows, cache
+        if not host_rows:
+            return rows[:, None], cache
+        obs.counter("lm.host_sample_steps").add(1)
+        return rows.astype(np.float32), cache   # exact widening
 
     # ---- request cursor ----
 
@@ -268,12 +290,16 @@ class LMDecodeAdapter(WorkloadAdapter):
         return len(cur.prompt)
 
     def feed(self, cur: _LMCursor):
+        if not cur.greedy:
+            self._host_rows = True  # the next step copies full logits
         p = cur.next_pos
         tok = cur.prompt[p] if p < len(cur.prompt) else cur.pending
         return np.asarray([tok], np.int32), p
 
     def _sample(self, cur: _LMCursor, row: np.ndarray) -> int:
         if cur.greedy:
+            if np.issubdtype(row.dtype, np.integer):
+                return int(row[0])      # picked on the device
             return int(row.argmax(-1))
         p = np.exp(row - row.max(-1, keepdims=True))
         p /= p.sum(-1, keepdims=True)

@@ -159,12 +159,9 @@ def test_lm_serving_spans_once_per_step_and_request(lm_adapter):
     obs.enable()
     sched = _serve(adapter, _lm_requests())
     _check_serving_spans(sched, 3, "lm.")
-    # each step copies float32 logits of both slots over the (padded)
-    # vocabulary
-    from repro.nn.layers import padded_vocab
+    # each greedy step copies one int32 token for each of both slots
     steps = len(sched.step_log)
-    assert obs.counter_values()["lm.bytes_to_host"] == \
-        steps * 2 * padded_vocab(cfg.vocab) * 4
+    assert obs.counter_values()["lm.bytes_to_host"] == steps * 2 * 4
 
 
 def test_vision_serving_spans_once_per_step_and_request(vision_adapter):
