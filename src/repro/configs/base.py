@@ -22,6 +22,12 @@ class MoeSpec:
     capacity_factor: float = 1.25
     group_size: int = 1024
     shared_expert: bool = True
+    # router: "softmax" (top-k of the softmax, unnormalised) | "sigmoid"
+    # (LFM2: top-k of sigmoid + expert_bias, the bias choosing only)
+    router: str = "softmax"
+    expert_bias: bool = False
+    norm_topk: bool = False     # renormalise the k weights (+1e-6)
+    routed_scale: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,6 +43,7 @@ class ModelConfig:
     head_dim: int = 0          # 0 -> d_model // n_heads
     act: str = "swiglu"
     norm: str = "rmsnorm"      # rmsnorm|layernorm|nonparam_ln|gemma_rmsnorm
+    norm_eps: float = 1e-6
     qkv_bias: bool = False
     tie_embeddings: bool = True
     scale_embed: bool = False  # gemma family: embed * sqrt(d)
@@ -49,6 +56,13 @@ class ModelConfig:
     rope_theta_local: Optional[float] = None
     # MoE
     moe: Optional[MoeSpec] = None
+    # hybrid stacks (LFM2): an explicit per-layer operator, "conv" (gated
+    # short conv of width d_conv) or "full_attention"; empty -> every
+    # layer is attention under `pattern`. With moe set, the first
+    # n_dense_layers feed-forwards are dense (d_ff), the rest MoE.
+    layer_types: Tuple[str, ...] = ()
+    n_dense_layers: int = 0
+    qk_norm: bool = False      # per-head RMSNorm on q and k before RoPE
     # vision cross-attn: one cross layer after every `cross_every` self
     # layers; n_layers counts BOTH kinds (llama-3.2-vision: 80 self+20 cross)
     cross_every: int = 0
@@ -57,7 +71,7 @@ class ModelConfig:
     dec_layers: int = 0
     # mamba
     d_state: int = 128
-    d_conv: int = 4
+    d_conv: int = 4            # also the hybrid short conv's width
     expand: int = 2
     headdim: int = 64
     ssd_chunk: int = 256

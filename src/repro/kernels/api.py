@@ -230,6 +230,24 @@ def xla_int_gemm(x_q, w_packed, *, w_bits: int, kappa=None, lam=None,
                           out_dtype=out_dtype)
 
 
+def xla_grouped_int_gemm(x_q, w_packed, *, w_bits: int, scale,
+                         out_dtype=jnp.bfloat16):
+    """`xla_int_gemm` over a group of weight matrices held side by side,
+    the experts of an MoE layer: one batched int dot, dequant epilogue.
+
+    x_q: (G, M, K_pad) int8, row block g meets matrix g; w_packed:
+    (G, K_pad/pf_w, N) chunk-planar packed along K, unpacked by the same
+    rule as a dense layer's; scale: (G, N) per-channel dequant scales.
+    Returns (G, M, N) ``out_dtype``.
+    """
+    w = packing.unpack(w_packed, w_bits, True, axis=1)
+    acc = jax.lax.dot_general(x_q, w, (((2,), (1,)), ((0,), (0,))),
+                              preferred_element_type=jnp.int32)
+    return apply_epilogue(acc, None, None, None, d=0, out_bits=8,
+                          epilogue="dequant", scale=scale[:, None, :],
+                          out_dtype=out_dtype)
+
+
 # ------------------------------------------------------------ qdot entry ---
 
 def _flatten_lead(x):

@@ -77,8 +77,15 @@ class Model:
         return logits[:, -1:], kvs
 
     def decode(self, params, cache, token, index, src_embed=None):
+        """(logits, cache), and for a hybrid MoE LM the experts each MoE
+        layer hit (``lm.decode_step``)."""
         return self._fns[2](params, cache, token, index, self.cfg,
                             src_embed=src_embed)
+
+    @property
+    def experts_hit_layers(self) -> int:
+        """Length of the experts hit a decode step returns (0: none)."""
+        return lm.experts_hit_layers(self.cfg)
 
     # ---- shapes for dry-run / launchers ----
     def input_specs(self, shape: ShapeConfig):

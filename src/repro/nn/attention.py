@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from repro.deploy.policy import PrecisionPlan, resolve_qcfg
 from repro.nn.layers import (QuantConfig, QOFF, dense_apply, dense_def,
-                             rope_apply, rope_single)
+                             norm_apply, norm_def, rope_apply, rope_single)
 from repro.parallel.ctx import active_mesh, constrain, constrain_first
 
 NEG_INF = -2.0e38
@@ -34,6 +34,9 @@ class AttnConfig:
     # by this block's param path + projection name (wq/wk/wv/wo)
     plan: Optional[PrecisionPlan] = None
     path: str = "layers/attn"
+    # per-head RMSNorm on q and k before RoPE (LFM2's q/k_layernorm)
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
 
     @property
     def groups(self):
@@ -45,7 +48,7 @@ class AttnConfig:
 
 def attn_def(cfg: AttnConfig, dtype=jnp.float32):
     d, h, hk, dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    return {
+    p = {
         "wq": dense_def(d, h * dh, ("embed", "heads"), bias=cfg.qkv_bias,
                         qcfg=cfg.q("wq"), dtype=dtype),
         "wk": dense_def(d, hk * dh, ("embed", "kv_heads"), bias=cfg.qkv_bias,
@@ -55,6 +58,20 @@ def attn_def(cfg: AttnConfig, dtype=jnp.float32):
         "wo": dense_def(h * dh, d, ("heads", "embed"), qcfg=cfg.q("wo"),
                         dtype=dtype),
     }
+    if cfg.qk_norm:
+        one = norm_def(dh, "rmsnorm", dtype)
+        p["q_norm"] = {"scale": dataclasses.replace(one["scale"],
+                                                     axes=(None,))}
+        p["k_norm"] = dict(p["q_norm"])
+    return p
+
+
+def _qk_norm(p, q, k, cfg: AttnConfig):
+    """Per-head RMSNorm over head_dim (identity unless cfg.qk_norm)."""
+    if not cfg.qk_norm:
+        return q, k
+    return (norm_apply(p["q_norm"], q, "rmsnorm", cfg.norm_eps),
+            norm_apply(p["k_norm"], k, "rmsnorm", cfg.norm_eps))
 
 
 def _split_heads(x, n, dh):
@@ -166,6 +183,7 @@ def attn_apply(p, x, cfg: AttnConfig, *, cos, sin, mode="causal",
         if kv_axes:
             k = constrain(k, kv_axes)
             v = constrain(v, kv_axes)
+        q, k = _qk_norm(p, q, k, cfg)
         q = rope_apply(q, cos, sin)
         k = rope_apply(k, cos, sin)
     else:
@@ -199,6 +217,68 @@ def init_cache(cfg: AttnConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
     return {"k": jnp.zeros(shape, store_t), "v": jnp.zeros(shape, store_t)}
 
 
+def init_layer_stack_cache(cfg: AttnConfig, layers: int, batch: int,
+                           max_len: int, dtype=jnp.bfloat16):
+    """Every layer's cache in one stack, heads merged into the minor dim:
+    (layers, batch, max_len, kv_heads * head_dim). Merged, a head_dim
+    under 128 does not pad the TPU's (8, 128) tiles."""
+    shape = (layers, batch, max_len, cfg.kv_heads * cfg.head_dim)
+    store_t = jnp.int8 if cfg.kv_quant_bits == 8 else dtype
+    return {"k": jnp.zeros(shape, store_t), "v": jnp.zeros(shape, store_t)}
+
+
+def store_rows(kv, layer, index, rows):
+    """``kv`` with ``rows`` (k, v), each (B, Hk*Dh), written at position
+    ``index`` (B,) of ``layer``: in place when ``kv`` is not needed after."""
+    r = jnp.arange(index.shape[0])
+    return {"k": kv["k"].at[layer, r, index].set(rows[0]),
+            "v": kv["v"].at[layer, r, index].set(rows[1])}
+
+
+def attn_decode_stacked(p, x, kv, index, cfg: AttnConfig, layer, *,
+                        theta=10000.0):
+    """One-token causal decode against ``layer`` (an int or a traced
+    index) of a stacked cache (`init_layer_stack_cache`) that it only
+    reads. x: (B,1,d); index: (B,) true positions. The token attends to
+    its slot's cached positions before ``index`` and to itself; the
+    arithmetic is `attn_decode`'s (the new K/V rounded to the cache's
+    type, a float32 softmax).
+
+    Returns (out, rows): ``rows`` are the (k, v) entries, (B, Hk*Dh),
+    that the caller stores at ``index`` (`store_rows`) once the layer
+    has read the stack.
+    """
+    b = x.shape[0]
+    h, hk, dh, g = cfg.n_heads, cfg.kv_heads, cfg.head_dim, cfg.groups
+    bits = cfg.kv_quant_bits
+    q = _split_heads(dense_apply(p["wq"], x, qcfg=cfg.q("wq")), h, dh)
+    k_new = _split_heads(dense_apply(p["wk"], x, qcfg=cfg.q("wk")), hk, dh)
+    v_new = _split_heads(dense_apply(p["wv"], x, qcfg=cfg.q("wv")), hk, dh)
+    q, k_new = _qk_norm(p, q, k_new, cfg)
+    q = rope_single(q, index, theta).reshape(b, 1, hk, g, dh)
+    kq = _kv_store(rope_single(k_new, index, theta), bits)
+    vq = _kv_store(v_new, bits)
+    t = kv["k"].shape[2]
+    k = _kv_load(kv["k"][layer].reshape(b, t, hk, dh), bits, x.dtype)
+    v = _kv_load(kv["v"][layer].reshape(b, t, hk, dh), bits, x.dtype)
+    kn, vn = _kv_load(kq, bits, x.dtype), _kv_load(vq, bits, x.dtype)
+    scale = dh ** -0.5
+    sc = jnp.einsum("bshgd,bthd->bhgst", q, k,
+                    preferred_element_type=jnp.float32) * scale
+    allow = jnp.arange(t)[None, :] < index[:, None]            # (B, T)
+    sc = jnp.where(allow[:, None, None, None, :], sc, NEG_INF)
+    sn = jnp.einsum("bshgd,bthd->bhgst", q, kn,
+                    preferred_element_type=jnp.float32) * scale
+    top = jnp.maximum(jnp.max(sc, axis=-1, keepdims=True), sn)
+    pc, pn = jnp.exp(sc - top), jnp.exp(sn - top)
+    den = jnp.sum(pc, axis=-1, keepdims=True) + pn
+    out = (jnp.einsum("bhgst,bthd->bshgd", (pc / den).astype(v.dtype), v)
+           + jnp.einsum("bhgst,bthd->bshgd", (pn / den).astype(vn.dtype),
+                        vn))
+    y = dense_apply(p["wo"], out.reshape(b, 1, h * dh), qcfg=cfg.q("wo"))
+    return y, (kq.reshape(b, -1), vq.reshape(b, -1))
+
+
 def attn_decode(p, x, cache, index, cfg: AttnConfig, *, theta=10000.0,
                 mode="causal", window=None, cross_kv=None,
                 ring: bool = False):
@@ -225,6 +305,7 @@ def attn_decode(p, x, cache, index, cfg: AttnConfig, *, theta=10000.0,
     if cross_kv is None:
         k_new = _split_heads(dense_apply(p["wk"], x, qcfg=cfg.q("wk")), hk, dh)
         v_new = _split_heads(dense_apply(p["wv"], x, qcfg=cfg.q("wv")), hk, dh)
+        q, k_new = _qk_norm(p, q, k_new, cfg)
         q = rope_single(q, index, theta)
         k_new = rope_single(k_new, index, theta)
         kq = _kv_store(k_new, cfg.kv_quant_bits)

@@ -149,6 +149,18 @@ def dense_apply(p, x, *, qcfg: QuantConfig = QOFF, precision=None):
     return y
 
 
+def quantize_activations(x, qcfg: QuantConfig):
+    """x (..., K) -> (int8 codes on the symmetric a_bits grid of the static
+    range ``a_absmax``, K padded to the packing chunk; the grid's step).
+    A8 caps at 127 (int8 containers)."""
+    absmax = qcfg.a_absmax or 4.0
+    a_max = packing.int_range(qcfg.a_bits, True)[1]
+    a_scale = absmax / a_max
+    x_q = jnp.clip(jnp.round(x.astype(jnp.float32) / a_scale), -a_max, a_max
+                   ).astype(jnp.int8)
+    return packing.pad_to_chunk(x_q, axis=-1), a_scale
+
+
 def _int_matmul(p, x, qcfg: QuantConfig):
     """W{8,4,2}A{8,4,2} integer GEMM with dequant epilogue.
 
@@ -163,13 +175,8 @@ def _int_matmul(p, x, qcfg: QuantConfig):
     """
     from repro.kernels.api import xla_int_gemm
 
-    absmax = qcfg.a_absmax or 4.0
-    a_max = packing.int_range(qcfg.a_bits, True)[1]  # A8 caps at 127 (int8)
-    a_scale = absmax / a_max
     k_logical = x.shape[-1]
-    x_q = jnp.clip(jnp.round(x.astype(jnp.float32) / a_scale), -a_max, a_max
-                   ).astype(jnp.int8)
-    x_q = packing.pad_to_chunk(x_q, axis=-1)
+    x_q, a_scale = quantize_activations(x, qcfg)
     if qcfg.segments is not None:
         # fine-grain mixed precision: each N-run is a uniform container
         # view of the flat segmented buffer — a static Python loop over

@@ -1,11 +1,19 @@
 """FFN (SwiGLU/GeGLU/GELU) and Mixture-of-Experts with expert parallelism.
 
-The MoE dispatch uses group-limited one-hot einsum dispatch (GShard-style
+Training MoE uses group-limited one-hot einsum dispatch (GShard-style
 with capacity factor), sized so the dispatch tensors stay modest; experts
 are sharded over the `model` mesh axis (EP). Sub-byte expert weights are the
 single biggest win of the paper's technique at LM scale: expert streaming is
 memory-bound, so packed int4/int2 experts cut the dominant roofline term by
 2-4x (see EXPERIMENTS.md).
+
+Serving MoE (`moe_dropless`, and every ``QuantConfig(mode="int")`` layer)
+drops nothing: every held expert runs on every token through one grouped
+int GEMM (`repro.kernels.api.xla_grouped_int_gemm`), so each expert's
+packed weights stream once a step, and the routing weights, zero off the
+top k, combine the outputs. Compute is ``n_experts / top_k`` times the
+routed work; bytes are what a grouped kernel would stream once every
+expert is hit, as every one is in a batch of a few hundred tokens.
 """
 from __future__ import annotations
 
@@ -16,7 +24,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.deploy.policy import PrecisionPlan, resolve_qcfg
-from repro.nn.layers import QOFF, QuantConfig, dense_apply, dense_def
+from repro.core import packing
+from repro.nn.layers import (QOFF, QuantConfig, dense_apply, dense_def,
+                             quantize_activations)
 from repro.nn.module import ParamDef
 from repro.parallel.ctx import constrain
 
@@ -81,6 +91,10 @@ class MoeConfig:
     qcfg: QuantConfig = QOFF
     plan: Optional[PrecisionPlan] = None
     path: str = "layers/moe"
+    router: str = "softmax"       # softmax | sigmoid (see MoeSpec)
+    expert_bias: bool = False
+    norm_topk: bool = False
+    routed_scale: float = 1.0
 
     def capacity(self, tokens_per_group: int) -> int:
         c = int(tokens_per_group * self.top_k * self.capacity_factor
@@ -88,18 +102,32 @@ class MoeConfig:
         return max(c, 4)
 
 
+def _expert_def(e, d_in, d_out, axes, qcfg: QuantConfig, dtype):
+    """One projection of every expert: float (E, d_in, d_out), or under
+    int mode chunk-planar W-bit containers packed along d_in with float32
+    per-channel scales, a dense layer's layout per expert."""
+    if qcfg.mode != "int":
+        return ParamDef((e, d_in, d_out), ("experts",) + axes, "normal",
+                        dtype)
+    kp = packing.padded_size(d_in) // packing.pack_factor(qcfg.w_bits)
+    return {"w_packed": ParamDef((e, kp, d_out), ("experts",) + axes,
+                                 "zeros", jnp.int8),
+            "w_scale": ParamDef((e, d_out), ("experts", axes[1]), "ones",
+                                jnp.float32)}
+
+
 def moe_def(cfg: MoeConfig, dtype=jnp.float32):
     e, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
+    q = cfg.qcfg
     p = {
         "router": ParamDef((d, e), ("embed", "experts"), "normal", dtype,
                            scale=0.02),
-        "wi": ParamDef((e, d, f), ("experts", "embed", "expert_mlp"),
-                       "normal", dtype),
-        "wg": ParamDef((e, d, f), ("experts", "embed", "expert_mlp"),
-                       "normal", dtype),
-        "wo": ParamDef((e, f, d), ("experts", "expert_mlp", "embed"),
-                       "normal", dtype),
+        "wi": _expert_def(e, d, f, ("embed", "expert_mlp"), q, dtype),
+        "wg": _expert_def(e, d, f, ("embed", "expert_mlp"), q, dtype),
+        "wo": _expert_def(e, f, d, ("expert_mlp", "embed"), q, dtype),
     }
+    if cfg.expert_bias:
+        p["expert_bias"] = ParamDef((e,), ("experts",), "zeros", dtype)
     if cfg.shared_expert:
         p["shared"] = mlp_def(
             MlpConfig(d, f, cfg.act, cfg.qcfg, cfg.plan,
@@ -119,7 +147,11 @@ def moe_apply(p, x, cfg: MoeConfig):
     outputs. No tensor larger than (g, E, C, d) ever exists.
 
     Returns (y, aux_loss). Router in float32; Switch load-balancing loss.
+    A packed (int mode) layer serves: it runs `moe_dropless`.
     """
+    if cfg.qcfg.mode == "int":
+        y, _ = moe_dropless(p, x, cfg)
+        return y, jnp.float32(0.0)
     b, s, d = x.shape
     gs = min(cfg.group_size, b * s)
     tokens = x.reshape(-1, d)
@@ -136,10 +168,7 @@ def moe_apply(p, x, cfg: MoeConfig):
     # replication. Tokens stay data-sharded / model-replicated.
     tokens = constrain(tokens.reshape(ng, gs, d), ("batch", None, None))
 
-    logits = jnp.einsum("gtd,de->gte", tokens.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, cfg.top_k)  # (g,t,k)
+    gate_vals, expert_idx, probs = moe_route(p, tokens, cfg)  # (g,t,k)
 
     cap = cfg.capacity(gs)
     e = cfg.n_experts
@@ -195,3 +224,69 @@ def moe_apply(p, x, cfg: MoeConfig):
     frac_prob = jnp.mean(probs, axis=1)
     aux = e * jnp.mean(jnp.sum(frac_tok * frac_prob, axis=-1))
     return y, aux
+
+
+def moe_route(p, x, cfg: MoeConfig):
+    """Router in float32 over tokens x (..., d) -> (weights (..., k)
+    float32, experts (..., k) int32, scores (..., E)).
+
+    softmax: the top k of the softmax, as they are. sigmoid (LFM2):
+    scores ``s = sigmoid(x W_r)``, the top k of ``s + expert_bias`` (the
+    bias only chooses), weights ``s`` of those, renormalised over the k
+    (+1e-6) when ``norm_topk``, times ``routed_scale``."""
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                        p["router"].astype(jnp.float32))
+    if cfg.router == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, idx = jax.lax.top_k(probs, cfg.top_k)
+        return weights, idx, probs
+    if cfg.router != "sigmoid":
+        raise ValueError(f"unknown router {cfg.router!r}")
+    s = jax.nn.sigmoid(logits)
+    choose = s + p["expert_bias"].astype(jnp.float32) if cfg.expert_bias \
+        else s
+    _, idx = jax.lax.top_k(choose, cfg.top_k)
+    weights = jnp.take_along_axis(s, idx, axis=-1)
+    if cfg.norm_topk:
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-6)
+    return weights * cfg.routed_scale, idx, s
+
+
+def _experts(p, x_e, cfg: MoeConfig, name: str, shared_input: bool):
+    """One projection of every expert. x_e: (N, d_in) when every expert
+    reads the same rows (``shared_input``), else (E, N, d_in); -> (E, N,
+    d_out) in x_e's dtype."""
+    w = p[name]
+    if cfg.qcfg.mode != "int":
+        eq = "nd,edf->enf" if shared_input else "end,edf->enf"
+        return jnp.einsum(eq, x_e, w.astype(x_e.dtype))
+    from repro.kernels.api import xla_grouped_int_gemm
+
+    x_q, a_scale = quantize_activations(x_e, cfg.qcfg)
+    if shared_input:
+        x_q = jnp.broadcast_to(x_q, (cfg.n_experts,) + x_q.shape)
+    return xla_grouped_int_gemm(x_q, w["w_packed"], w_bits=cfg.qcfg.w_bits,
+                                scale=w["w_scale"] * a_scale,
+                                out_dtype=x_e.dtype)
+
+
+def moe_dropless(p, x, cfg: MoeConfig):
+    """Every (token, expert) pair the router picks, none dropped. x: (B,
+    S, d) -> (y (B, S, d), experts hit: int32, the number of experts that
+    at least one token of x picked)."""
+    b, s, d = x.shape
+    tokens = x.reshape(-1, d)
+    weights, idx, _ = moe_route(p, tokens, cfg)
+    picked = jax.nn.one_hot(idx, cfg.n_experts, dtype=jnp.float32)
+    comb = jnp.einsum("nk,nke->ne", weights, picked)          # (N, E)
+    hits = jnp.sum(jnp.any(picked > 0, axis=(0, 1))).astype(jnp.int32)
+    h = _experts(p, tokens, cfg, "wi", True)
+    g = _experts(p, tokens, cfg, "wg", True)
+    out = _experts(p, _act(h, g, cfg.act), cfg, "wo", False)  # (E, N, d)
+    y = jnp.einsum("ne,end->nd", comb, out.astype(jnp.float32))
+    y = y.astype(x.dtype).reshape(b, s, d)
+    if cfg.shared_expert:
+        y = y + mlp_apply(p["shared"], x,
+                          MlpConfig(cfg.d_model, cfg.d_ff, cfg.act, cfg.qcfg,
+                                    cfg.plan, f"{cfg.path}/shared"))
+    return y, hits

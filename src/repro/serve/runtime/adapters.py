@@ -26,9 +26,14 @@ outputs) and ``logits_to_host`` (the copy of the step's host outputs:
 for the LM, one greedy int32 token per slot, or the last position's
 full logits in a step that feeds a sampled request). The LM also counts
 ``lm.bytes_to_host`` and ``lm.host_sample_steps``, the steps that
-copied full logits. Off, the step does what it did unspanned: the copy
-itself blocks on the program. On, the device runs the same work in the
-same order: everything is queued before the wait.
+copied full logits. A hybrid MoE LM's step also returns, after the
+tokens and in the same copy, how many experts each MoE layer's tokens
+picked, which the adapter adds to ``moe.experts_hit``. Clearing a
+re-admitted slot's carried state (SSM, RG-LRU or conv rows) is the span
+``lm.state_reset``, inside the scheduler's ``serve.admit``. Off, the step
+does what it did unspanned: the copy itself blocks on the program. On,
+the device runs the same work in the same order: everything is queued
+before the wait.
 
 Per-request bit-exactness invariant: every adapter's step must be
 row-independent (slot *i*'s outputs depend only on slot *i*'s feeds),
@@ -47,7 +52,8 @@ from repro.obs import trace as obs
 
 # per-slot carried state that must be cleared on slot reuse, keyed by the
 # cache subtree name: leaves are (layers, slots, ...) with zero init
-STATE_RESET_KEYS = ("ssm", "rec")
+# (SSM, RG-LRU and short-conv rows)
+STATE_RESET_KEYS = ("ssm", "rec", "conv")
 
 
 @dataclasses.dataclass
@@ -158,16 +164,25 @@ class LMDecodeAdapter(WorkloadAdapter):
 
     The jitted step picks each slot's greedy token on the device (the
     lowest index among the largest logits, as ``np.argmax`` picks), so a
-    step copies one int32 per slot to the host. A step that feeds a
+    step copies one int32 per slot to the host, and for a hybrid MoE LM
+    one more per MoE layer, its experts hit. A step that feeds a
     sampled cursor (``feed`` marks it) copies the last position's full
-    logits instead, and its greedy cursors take the argmax of their row:
+    logits as well, and its greedy cursors take the argmax of their row:
     the same token.
+
+    ``donate_state`` donates the step's input cache to the program, which
+    then updates it in place: one cache on the device instead of the
+    input's and the output's (the scheduler drops the old state when the
+    step returns). Undonated, XLA copies a cache that the step updates in
+    place into a fresh buffer every step: for large caches, bytes and
+    time of their own.
     """
 
     name = "lm"
 
     def __init__(self, model, params, max_len: int, *, eos_id: int = 1,
-                 mesh=None, dp_axis: str = "data", plan=None):
+                 mesh=None, dp_axis: str = "data", plan=None,
+                 donate_state: bool = False):
         import jax
         import jax.numpy as jnp
 
@@ -182,13 +197,18 @@ class LMDecodeAdapter(WorkloadAdapter):
             params = jax.device_put(params, NamedSharding(mesh, P()))
         self.params = params
         self._host_rows = False     # a sampled cursor was fed this step
+        self._n_hits = model.experts_hit_layers
 
         def decode(params, cache, token, index):
-            logits, cache = model.decode(params, cache, token, index)
+            logits, cache, *hits = model.decode(params, cache, token, index)
             last = logits[:, -1]                                 # (B, V)
-            return jnp.argmax(last, -1).astype(jnp.int32), last, cache
+            picks = jnp.argmax(last, -1).astype(jnp.int32)
+            if hits:
+                picks = jnp.concatenate([picks, hits[0]])
+            return picks, last, cache
 
-        self._decode = jax.jit(decode)
+        self._decode = jax.jit(decode,
+                               donate_argnums=(1,) if donate_state else ())
 
     # ---- placement (same layout as the wave engine) ----
 
@@ -228,16 +248,17 @@ class LMDecodeAdapter(WorkloadAdapter):
         import jax
         import jax.numpy as jnp
 
-        mask = jnp.asarray(slot_mask)
+        with obs.span("lm.state_reset", cat="lm"):
+            mask = jnp.asarray(slot_mask)
 
-        def clear(leaf):
-            m = mask.reshape((1, mask.shape[0]) + (1,) * (leaf.ndim - 2))
-            return jnp.where(m, jnp.zeros_like(leaf), leaf)
+            def clear(leaf):
+                m = mask.reshape((1, mask.shape[0]) + (1,) * (leaf.ndim - 2))
+                return jnp.where(m, jnp.zeros_like(leaf), leaf)
 
-        out = dict(cache)
-        for k in keys:
-            out[k] = jax.tree.map(clear, cache[k])
-        return self.place_state(out, self.mesh, self.dp_axis)
+            out = dict(cache)
+            for k in keys:
+                out[k] = jax.tree.map(clear, cache[k])
+            return self.place_state(out, self.mesh, self.dp_axis)
 
     # ---- engine step ----
 
@@ -249,16 +270,22 @@ class LMDecodeAdapter(WorkloadAdapter):
         like its feed row ((B, 1) int32), or, in a step that fed a
         sampled cursor, the last position's logits ((B, V) float32)."""
         with obs.span("lm.dispatch", cat="lm"):
-            tokens, last, cache = self._decode(
+            picks, last, cache = self._decode(
                 self.params, cache, self._put_wave(feed),
                 self._put_wave(positions.astype(np.int32)))
         host_rows, self._host_rows = self._host_rows, False
-        out = last if host_rows else tokens
+        n = self._n_hits
+        out = ((last, picks) if n else (last,)) if host_rows else (picks,)
         with obs.span("lm.device_wait", cat="lm") as sp:
             sp.sync((out, cache))
         with obs.span("lm.logits_to_host", cat="lm"):
-            rows = np.asarray(out)
-        obs.counter("lm.bytes_to_host").add(rows.nbytes)
+            got = [np.asarray(o) for o in out]
+        obs.counter("lm.bytes_to_host").add(sum(g.nbytes for g in got))
+        rows = got[0]
+        if n:
+            obs.counter("moe.experts_hit").add(int(got[-1][-n:].sum()))
+            if not host_rows:
+                rows = rows[:-n]
         if not host_rows:
             return rows[:, None], cache
         obs.counter("lm.host_sample_steps").add(1)

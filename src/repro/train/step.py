@@ -115,7 +115,8 @@ def make_decode_fns(model: Model, mesh: Mesh, shape: ShapeConfig,
 
     def decode_step(params, cache, token, index):
         with activation_sharding(mesh, rules):
-            logits, new_cache = model.decode(params, cache, token, index)
+            logits, new_cache = model.decode(params, cache, token,
+                                             index)[:2]
             return logits, new_cache
 
     shard = {

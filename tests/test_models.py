@@ -63,7 +63,7 @@ def test_grad_finite(modname):
 
 
 def test_all_archs_registered():
-    assert len(list_archs()) == 10
+    assert len(list_archs()) == 11
 
 
 def test_full_configs_match_assignment():
