@@ -77,10 +77,15 @@ class QuantSpec:
         return QuantSpec(bits=bits, signed=True, alpha=-absmax, beta=absmax)
 
 
-def quantize(t, spec: QuantSpec):
-    """Real tensor -> integer image (int8 container), eq. (1) inverted."""
+def quantize(t, spec: QuantSpec, eps=None):
+    """Real tensor -> integer image (int8 container), eq. (1) inverted.
+
+    ``eps`` (a float32 array holding ``spec.eps``) makes the divisor an
+    operand of a jitted caller: XLA turns a division by a constant into
+    a multiply by its float32 reciprocal, which rounds a near-tie the
+    other way from the division an eager call makes."""
     zero = 0.0 if spec.signed else spec.alpha
-    t_hat = jnp.round((t - zero) / spec.eps)
+    t_hat = jnp.round((t - zero) / (spec.eps if eps is None else eps))
     t_hat = jnp.clip(t_hat, spec.int_min, spec.int_max)
     return t_hat.astype(jnp.int8)
 

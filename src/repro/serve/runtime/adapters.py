@@ -360,7 +360,7 @@ class LMDecodeAdapter(WorkloadAdapter):
 
 @dataclasses.dataclass
 class _VisionCursor:
-    payload: np.ndarray             # quantized integer image (H, W, C)
+    payload: np.ndarray             # float32 image (H, W, C)
     rid: int
     out: Optional[np.ndarray] = None
     done: bool = False
@@ -371,8 +371,11 @@ class VisionAdapter(WorkloadAdapter):
     one engine step is one batched integer forward, and every admitted
     request finishes after exactly one step (admission is the only
     scheduling decision, so continuous batching == don't wait for a full
-    wave). Images are quantized per request with the net's input spec —
-    elementwise, so identical to the wave engine's whole-batch quantize.
+    wave). `begin` keeps the float32 image as it came and touches no
+    device; the step's one jitted program quantizes the whole feed with
+    the net's input spec (one elementwise op beside the convs, on each
+    chip's shard under ``mesh=``), then runs the integer forward. Pad
+    slots are zeros, which quantize to 0.
     """
 
     name = "vision"
@@ -381,7 +384,9 @@ class VisionAdapter(WorkloadAdapter):
     def __init__(self, qnet, *, mesh=None, dp_axis: str = "data",
                  backend: Optional[str] = None):
         import jax
+        import jax.numpy as jnp
 
+        from repro.core.quantize import quantize
         from repro.vision.models import forward_int
 
         self.qnet = qnet
@@ -389,11 +394,15 @@ class VisionAdapter(WorkloadAdapter):
         self.dp_axis = dp_axis
         self.backend = backend
 
-        def vision_forward(xh):
-            return forward_int(qnet, xh, backend=backend, mesh=mesh)
+        def vision_forward(x, eps):
+            x_hat = quantize(x, qnet.input_spec, eps=eps)
+            return forward_int(qnet, x_hat, backend=backend, mesh=mesh)
 
         self._forward = jax.jit(vision_forward)
-        self._spec = ((*qnet.cfg.in_hw, qnet.cfg.in_ch), np.int8)
+        # the input grid as an operand: the program divides by it exactly
+        # as an eager `quantize_input` does (see `quantize`)
+        self._eps = jnp.float32(qnet.input_spec.eps)
+        self._spec = ((*qnet.cfg.in_hw, qnet.cfg.in_ch), np.float32)
 
     def input_spec(self):
         return self._spec
@@ -402,7 +411,7 @@ class VisionAdapter(WorkloadAdapter):
         import jax.numpy as jnp
 
         with obs.span("vision.dispatch", cat="vision"):
-            logits = self._forward(jnp.asarray(feed))
+            logits = self._forward(jnp.asarray(feed), self._eps)
         with obs.span("vision.device_wait", cat="vision") as sp:
             sp.sync(logits)
         with obs.span("vision.logits_to_host", cat="vision"):
@@ -411,11 +420,8 @@ class VisionAdapter(WorkloadAdapter):
 
     def begin(self, payload, *, rid: int, greedy: bool = True,
               seed: int = 0):
-        from repro.vision.models import quantize_input
-
-        img = np.asarray(payload, np.float32)
-        x_hat = np.asarray(quantize_input(self.qnet, img[None]))[0]
-        return _VisionCursor(payload=x_hat, rid=rid)
+        return _VisionCursor(payload=np.asarray(payload, np.float32),
+                             rid=rid)
 
     def reserve_tokens(self, cur) -> int:
         return 1

@@ -306,8 +306,9 @@ def test_pallas_conv_and_segmented_calls_carry_names():
 
 def test_vision_step_is_a_named_program(vision_adapter):
     adapter, images = vision_adapter
-    feed = np.zeros((2, *adapter.input_spec()[0]), np.int8)
-    text = adapter._forward.lower(jnp.asarray(feed)).as_text()
+    shape, dtype = adapter.input_spec()
+    feed = np.zeros((2, *shape), dtype)
+    text = adapter._forward.lower(jnp.asarray(feed), adapter._eps).as_text()
     assert "jit_vision_forward" in text
 
 
