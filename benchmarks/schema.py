@@ -12,10 +12,6 @@ is pinned here and validated by tests/test_bench_schema.py:
                       packed_bytes columns)
   BENCH_cluster.json  fig9_cluster_scaling  {version, gemm, path, rows}
   BENCH_e2e.json      e2e_networks          {version, batch, rows}
-  BENCH_serving.json  benchmarks/loadgen    {version, workload, rows,
-                      acceptance} — per-policy serving stats; the
-                      validator enforces continuous > wave on both
-                      token throughput and p99 latency
   BENCH_trace.json    repro.obs             Chrome trace-event object +
                       the "repro" payload (counters, op counters,
                       dispatch log) — `check_trace`
@@ -169,68 +165,6 @@ def validate_e2e(payload) -> None:
                 _typed(r[opt], types, f"{p}.{opt}", check)
 
 
-# ------------------------------------------------------ BENCH_serving ---
-
-def _serving_stats(r, p):
-    lat = _need(r, "latency_s", dict, p)
-    for k in ("p50", "p95", "p99", "mean", "max"):
-        _need(lat, k, _NUM, f"{p}.latency_s", lambda v: v >= 0)
-    qd = _need(r, "queue_depth", dict, p)
-    _need(qd, "mean", _NUM, f"{p}.queue_depth", lambda v: v >= 0)
-    _need(qd, "max", int, f"{p}.queue_depth", lambda v: v >= 0)
-    occ = _need(r, "occupancy", dict, p)
-    _need(occ, "mean", _NUM, f"{p}.occupancy", lambda v: 0 <= v <= 1)
-    _need(occ, "min", _NUM, f"{p}.occupancy", lambda v: 0 <= v <= 1)
-
-
-def validate_serving(payload) -> None:
-    """benchmarks/loadgen payload: one row per scheduling policy on the
-    same seeded open-loop workload, plus the acceptance comparison —
-    continuous batching must be strictly better than the synchronous
-    wave baseline on token throughput AND p99 latency at the same
-    offered load (the PR-8 acceptance shape, enforced like the fig8
-    pipelined-roofline ordering)."""
-    _need(payload, "version", int, "$", lambda v: v == 1)
-    w = _need(payload, "workload", dict, "$")
-    _need(w, "model", str, "$.workload")
-    _need(w, "requests", int, "$.workload", lambda v: v >= 1)
-    _need(w, "qps", _NUM, "$.workload", lambda v: v > 0)
-    _need(w, "step_cost_s", _NUM, "$.workload", lambda v: v > 0)
-    _need(w, "slots", int, "$.workload", lambda v: v >= 1)
-    _need(w, "seed", int, "$.workload")
-    _need(w, "devices", int, "$.workload", lambda v: v >= 1)
-    rows = _rows(payload, "$")
-    by_policy = {}
-    for i, r in enumerate(rows):
-        p = f"$.rows[{i}]"
-        _typed(r, dict, p)
-        pol = _need(r, "policy", str, p,
-                    lambda v: v in ("wave", "continuous"))
-        by_policy[pol] = r
-        _need(r, "requests", int, p, lambda v: v >= 1)
-        _need(r, "steps", int, p, lambda v: v >= 1)
-        _need(r, "tokens_out", int, p, lambda v: v >= 0)
-        _need(r, "makespan_s", _NUM, p, lambda v: v > 0)
-        _need(r, "throughput_rps", _NUM, p, lambda v: v > 0)
-        _need(r, "throughput_tps", _NUM, p, lambda v: v > 0)
-        _serving_stats(r, p)
-    for pol in ("wave", "continuous"):
-        if pol not in by_policy:
-            _fail("$.rows", f"missing policy row {pol!r}")
-    acc = _need(payload, "acceptance", dict, "$")
-    gain = _need(acc, "throughput_gain", _NUM, "$.acceptance")
-    p99 = _need(acc, "p99_ratio", _NUM, "$.acceptance")
-    wave, cont = by_policy["wave"], by_policy["continuous"]
-    if cont["throughput_tps"] <= wave["throughput_tps"] or gain <= 1.0:
-        _fail("$.acceptance.throughput_gain",
-              "continuous batching does not beat the wave baseline "
-              "on token throughput")
-    if cont["latency_s"]["p99"] >= wave["latency_s"]["p99"] or p99 >= 1.0:
-        _fail("$.acceptance.p99_ratio",
-              "continuous batching does not beat the wave baseline "
-              "on p99 latency")
-
-
 # ----------------------------------------------------- BENCH_accuracy ---
 
 _ACC_MODES = ("float", "ptq", "qat")
@@ -376,7 +310,6 @@ VALIDATORS = {
     "BENCH_kernels.json": validate_kernels,
     "BENCH_cluster.json": validate_cluster,
     "BENCH_e2e.json": validate_e2e,
-    "BENCH_serving.json": validate_serving,
     "BENCH_accuracy.json": validate_accuracy,
     "BENCH_trace.json": check_trace,
 }

@@ -232,12 +232,23 @@ def deploy_resnet8():
     return qnet, images
 
 
-def phase_cnn():
-    """ResNet-8 at full width served through `VisionEngine` on the pallas
-    backend in both pipeline modes, bit-exact vs the xla backend."""
+def serve_images(qnet, images, mesh=None):
+    """Serve ``images`` through the `Scheduler` over a `VisionAdapter`
+    with 8 slots; returns (int32 logits, the scheduler)."""
     import numpy as np
 
-    from repro.serve.engine import VisionEngine
+    from repro.serve.runtime import Scheduler, VisionAdapter
+
+    sched = Scheduler(VisionAdapter(qnet, mesh=mesh), 8, mesh=mesh)
+    return np.stack(sched.serve(list(images))), sched
+
+
+def phase_cnn():
+    """ResNet-8 at full width served through the `Scheduler` on the
+    pallas backend in both pipeline modes, bit-exact vs the xla
+    backend."""
+    import numpy as np
+
     from repro.vision.models import forward_int, quantize_input
 
     t0 = time.time()
@@ -248,16 +259,14 @@ def phase_cnn():
     for pipe in PIPELINES:
         os.environ["REPRO_QPIPELINE"] = pipe
         try:
-            eng = VisionEngine(qnet, batch_size=8)
-            got = on_pallas(lambda: eng.run(images),
-                            f"resnet8 VisionEngine {pipe}", pipe)
+            got, sched = on_pallas(lambda: serve_images(qnet, images),
+                                   f"resnet8 Scheduler {pipe}", pipe)
         finally:
             del os.environ["REPRO_QPIPELINE"]
         bit_exact(got, want, f"resnet8 logits {pipe}")
-        lat = eng.utilization_report()["latency_us"]
-        log(f"[cnn] {pipe}: {len(images)} images in {lat['waves']} waves "
-            f"of 8, logits bit-exact vs xla, preds "
-            f"{got.argmax(-1).tolist()}")
+        log(f"[cnn] {pipe}: {len(images)} images in "
+            f"{len(sched.step_log)} steps over 8 slots, logits bit-exact "
+            f"vs xla, preds {got.argmax(-1).tolist()}")
     log(f"[cnn] done ({time.time() - t0:.1f}s incl. calibration and "
         "compile)")
 
@@ -272,7 +281,7 @@ def phase_lm():
     from repro.launch.serve import build_serving, make_requests
     from repro.models.api import get_config
     from repro.nn.module import param_bytes
-    from repro.serve.engine import Engine
+    from repro.serve.runtime import LMDecodeAdapter, Scheduler
 
     t0 = time.time()
     cfg = get_config("qwen2.5-3b")
@@ -283,9 +292,9 @@ def phase_lm():
         f"{time.time() - t0:.1f}s")
 
     reqs = make_requests(cfg, 4, 16, SEED)
-    eng = Engine(model, params, batch_size=4, max_len=64)
+    sched = Scheduler(LMDecodeAdapter(model, params, 64), 4)
     t1 = time.time()
-    out = eng.generate(reqs)
+    out = sched.serve(reqs)
     dt = time.time() - t1
     toks = [len(r.out) for r in out]
     check(all(1 <= t <= 16 for t in toks), f"[lm] tokens out {toks}")
@@ -318,13 +327,12 @@ def phase_lm():
 
 def phase_cluster(devs):
     """The cluster path on four chips: sharded qdot/qconv on (2,2) and
-    (4,1) meshes bit-exact vs one chip, and the ResNet-8 VisionEngine at
-    dp=4 bit-exact vs meshless."""
+    (4,1) meshes bit-exact vs one chip, and ResNet-8 served through the
+    `Scheduler` at dp=4 bit-exact vs meshless."""
     import numpy as np
 
     from repro.kernels import api
     from repro.parallel.ctx import make_mesh
-    from repro.serve.engine import VisionEngine
 
     rng = np.random.default_rng(SEED)
     meshes = {(dp, tp): make_mesh((dp, tp), ("data", "model"), devices=devs)
@@ -361,19 +369,21 @@ def phase_cluster(devs):
         f"{len(devs)} chips ({time.time() - t0:.1f}s incl. compile)")
 
     qnet, images = deploy_resnet8()
-    want = on_pallas(lambda: VisionEngine(qnet, batch_size=8).run(images),
-                     "resnet8 meshless")
-    eng = VisionEngine(qnet, batch_size=8, mesh=meshes[(4, 1)])
-    got = on_pallas(lambda: eng.run(images), "resnet8 dp=4")
+    want, _ = on_pallas(lambda: serve_images(qnet, images),
+                        "resnet8 meshless")
+    got, sched = on_pallas(lambda: serve_images(qnet, images,
+                                                meshes[(4, 1)]),
+                           "resnet8 dp=4")
     bit_exact(got, want, "resnet8 dp=4 logits")
-    per = eng.utilization_report()["per_device"]
+    per = np.mean([s["per_device"] for s in sched.step_log],
+                  axis=0).tolist()
     check(len(per) == len(devs) and min(per) > 0,
-          f"[cluster] dp=4 per-device utilization {per}")
+          f"[cluster] dp=4 per-device occupancy {per}")
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
              for d in devs]
     check(all(peaks), f"[cluster] a chip allocated nothing: {peaks}")
-    log(f"[cluster] resnet8 VisionEngine dp=4: logits bit-exact vs "
-        f"meshless, per-device utilization {per}, peak_bytes_in_use "
+    log(f"[cluster] resnet8 Scheduler dp=4: logits bit-exact vs "
+        f"meshless, per-device occupancy {per}, peak_bytes_in_use "
         f"per chip {peaks}")
 
 

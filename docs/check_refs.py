@@ -52,8 +52,8 @@ def check_paper_map(errors: list):
     # ISSUE-6 to include the Mac&Load pipeline row: the autotune cache,
     # the differential harness, and the benchmark-artifact schema, by
     # ISSUE-7 to include the observability subsystem, by ISSUE-8 to
-    # include the continuous-batching serving runtime and its load
-    # generator, by ISSUE-9 to include the fine-grain mixed-precision
+    # include the continuous-batching serving runtime and its benchmark
+    # (now the on-chip cells' driver), by ISSUE-9 to include the fine-grain mixed-precision
     # stack: segmented containers, the mixed-operand kernel wall, and the
     # channel-group planner, and by ISSUE-10 to include the QAT→deploy
     # accuracy subsystem: STE fake-quant, task-loss calibration, and the
@@ -77,7 +77,7 @@ def check_paper_map(errors: list):
         "src/repro/serve/runtime/scheduler.py",
         "src/repro/serve/runtime/slots.py",
         "src/repro/serve/runtime/adapters.py",
-        "benchmarks/loadgen.py",
+        "benchmarks/chip/run.py",
         "tests/test_runtime.py",
         "src/repro/obs/trace.py",
         "src/repro/obs/counters.py",

@@ -1,5 +1,6 @@
 """End-to-end serving driver (the paper's kind is inference): serve a small
-LM with batched requests, comparing fp vs packed sub-byte weights.
+LM with batched requests through the continuous-batching `Scheduler`,
+comparing fp vs packed sub-byte weights.
 
     PYTHONPATH=src python examples/serve_quantized.py
 """
@@ -12,7 +13,7 @@ import numpy as np
 from repro.configs.qwen2p5_3b import smoke_config
 from repro.models.api import build
 from repro.nn.layers import QuantConfig, pack_dense_weights
-from repro.serve.engine import Engine, Request
+from repro.serve.runtime import LMDecodeAdapter, Request, Scheduler
 
 
 def fill_packed(qp, fp):
@@ -44,9 +45,9 @@ reqs = [Request(prompt=np.array([2 + i, 40 + i, 7], np.int32),
 
 for name, model, params in [("fp32", model_fp, params_fp),
                             ("w4a8+int8kv", model_q, params_q)]:
-    eng = Engine(model, params, batch_size=4, max_len=32)
+    sched = Scheduler(LMDecodeAdapter(model, params, max_len=32), 4)
     t0 = time.time()
-    out = eng.generate([dataclasses.replace(r) for r in reqs])
+    out = sched.serve([dataclasses.replace(r) for r in reqs])
     dt = time.time() - t0
     toks = sum(len(r.out) for r in out)
     print(f"[{name}] {toks} tokens in {dt:.2f}s; "

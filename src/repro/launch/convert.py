@@ -23,5 +23,5 @@ def convert_params(q_tree, fp_tree, w_bits: int):
 
 def artifact_bytes(params) -> int:
     """Total bytes of a (packed or fp) parameter tree — one accounting
-    (`nn/module.py::param_bytes`) shared by converter, engine, and CLIs."""
+    (`nn/module.py::param_bytes`) shared by the converter and the CLIs."""
     return param_bytes(params)

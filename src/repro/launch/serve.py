@@ -1,6 +1,7 @@
 """Serving launcher: load (or init) params, optionally convert to the
 packed sub-byte deployment artifact, and serve a batch of synthetic
-requests through the engine.
+requests through the continuous-batching `Scheduler` over an
+`LMDecodeAdapter` — the loop the chip benchmark measures.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b --smoke \
         --quant w4a8 --requests 8 --max-new 16
@@ -14,9 +15,9 @@ plan-resolved bit-width instead of one uniform --quant:
 
 Cluster-parallel serving: ``--mesh dp,tp`` builds a (data=dp, model=tp)
 device mesh (the paper's N-core cluster; on CPU force host devices with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``), shards request
-waves data-parallel over the `data` axis, and prints the per-device slot
-utilization report after serving:
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``), shards the slot
+array data-parallel over the `data` axis, and prints each device's mean
+slot occupancy over the serve's steps:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b --smoke \
@@ -37,7 +38,7 @@ from repro.models.api import build, get_config
 from repro.nn.layers import QuantConfig
 from repro.obs import trace as obs
 from repro.parallel.ctx import make_mesh
-from repro.serve.engine import Engine, Request
+from repro.serve.runtime import LMDecodeAdapter, Request, Scheduler
 
 
 def build_serving(cfg, *, quant: str = "off", plan_path=None, ckpt=None,
@@ -91,6 +92,25 @@ def make_requests(cfg, n: int, max_new: int, seed: int = 0):
         max_new_tokens=max_new) for _ in range(n)]
 
 
+def print_serving_report(sched: Scheduler, mesh=None) -> None:
+    """Request latency and queue depth from `Scheduler.serving_report`,
+    and with a mesh each device's slot occupancy averaged over the
+    scheduler's steps."""
+    rep = sched.serving_report()
+    lat, qd = rep["latency"], rep["queue_depth"]
+    if lat is not None and qd is not None:
+        print(f"request latency: p50={lat['p50'] * 1e3:.1f}ms "
+              f"p95={lat['p95'] * 1e3:.1f}ms p99={lat['p99'] * 1e3:.1f}ms "
+              f"over {rep['requests']} request(s); queue depth mean "
+              f"{qd['mean']:.1f} max {qd['max']}")
+    if mesh is not None and sched.step_log:
+        per = np.mean([s["per_device"] for s in sched.step_log], axis=0)
+        cols = " ".join(f"d{d}={u:.0%}" for d, u in enumerate(per))
+        print(f"per-device occupancy: mean {per.mean():.0%} over "
+              f"{rep['steps']} step(s) [{cols}] — an idle device holds "
+              "only empty slots")
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -109,8 +129,8 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--mesh", default=None, metavar="DP,TP",
                     help="serve on a (data=DP, model=TP) device mesh, "
-                         "e.g. --mesh 4,2; waves are sharded "
-                         "data-parallel over DP (batch must divide DP). "
+                         "e.g. --mesh 4,2; the slot array is sharded "
+                         "data-parallel over DP. "
                          "On CPU, export XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N first")
     args = ap.parse_args()
@@ -150,45 +170,34 @@ def main():
           f"({pbytes:,} bytes)")
 
     reqs = make_requests(cfg, args.requests, args.max_new, args.seed)
-    eng = Engine(model, params, batch_size=args.batch, max_len=args.max_len,
-                 plan=plan, mesh=mesh)
+    adapter = LMDecodeAdapter(model, params, args.max_len, mesh=mesh,
+                              plan=plan)
+    sched = Scheduler(adapter, args.batch, mesh=mesh)
     if mesh is not None:
         print(f"mesh: data={mesh.shape['data']} model={mesh.shape['model']} "
-              f"({len(mesh.devices.flat)} devices; waves sharded over "
+              f"({len(mesh.devices.flat)} devices; slots sharded over "
               "'data')")
     if mode != "off":
-        from repro.kernels.api import ENV_VAR
-        kb = eng.kernel_backends()
-        print(f"kernel backends: qdot={kb['qdot']} qconv={kb['qconv']} "
-              f"(override: {ENV_VAR} or QuantConfig.backend)")
+        from repro.kernels import api
+        print(f"kernel backends: qdot={api.default_backend('qdot')} "
+              f"qconv={api.default_backend('qconv')} "
+              f"(override: {api.ENV_VAR} or QuantConfig.backend)")
     t0 = time.time()
     with obs.span("serve.generate", cat="serve", requests=len(reqs),
                   batch=args.batch):
-        out = eng.generate(reqs)
+        out = sched.serve(reqs)
     dt = time.time() - t0
     toks = sum(len(r.out) for r in out)
     dev = jax.devices()[0]
     print(f"{toks} tokens / {dt:.2f}s = {toks / dt:.1f} tok/s on "
           f"{dev.platform}:{dev.device_kind} (includes compilation)")
-    rep = eng.utilization_report()
-    lat = rep["latency_us"]
-    if lat is not None:
-        qd = rep["queue_depth"]
-        print(f"wave latency: p50={lat['p50'] / 1e3:.1f}ms "
-              f"p95={lat['p95'] / 1e3:.1f}ms p99={lat['p99'] / 1e3:.1f}ms "
-              f"over {lat['waves']} wave(s); queue depth mean "
-              f"{qd['mean']:.1f} max {qd['max']}")
-    if mesh is not None:
-        per = " ".join(f"d{d}={u:.0%}" for d, u in
-                       enumerate(rep["per_device"]))
-        print(f"cluster utilization: {rep['mean_util']:.0%} over "
-              f"{rep['waves']} wave(s) [{per}] — idle devices == padded "
-              "slots")
+    print_serving_report(sched, mesh)
     for r in out[:3]:
         print("  prompt", r.prompt.tolist(), "->", r.out.tolist())
     trace_path = obs.export_if_configured("serve_trace.json")
     if trace_path:
         print(f"trace -> {trace_path} (render: python -m repro.obs.report)")
+    return out
 
 
 if __name__ == "__main__":

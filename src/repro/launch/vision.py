@@ -4,15 +4,16 @@ The CNN analogue of `repro.launch.deploy` + `repro.launch.serve` in one
 CLI: build a paper-class network (`repro.vision.configs`), calibrate it
 on images (random in --smoke runs), search a per-layer W{8,4,2} plan,
 pack the integer artifact, and serve an image batch through the
-`VisionEngine` (optionally mesh-sharded):
+continuous-batching `Scheduler` over a `VisionAdapter` (optionally
+mesh-sharded):
 
     PYTHONPATH=src python -m repro.launch.vision --net resnet8 --smoke \
         --budget auto --out vplan.json --requests 6 --batch 4
 
 ``--from-plan plan.json`` skips calibration/search and re-packs from an
 existing plan artifact (the round-trip CI exercises); ``--mesh dp,tp``
-serves waves data-parallel on a device mesh (tp shards conv output
-channels inside the kernels when it divides them).
+shards the slot array data-parallel on a device mesh (tp shards conv
+output channels inside the kernels when it divides them).
 """
 from __future__ import annotations
 
@@ -80,9 +81,11 @@ def main():
     import numpy as np
 
     from repro.deploy.policy import load_plan, save_plan
+    from repro.kernels import api
     from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.serve import print_serving_report
     from repro.parallel.ctx import make_mesh
-    from repro.serve.engine import VisionEngine
+    from repro.serve.runtime import Scheduler, VisionAdapter
     from repro.vision.configs import get_vision_config
     from repro.vision.models import (collect_absmax, init_fp, quantize_net,
                                      vision_artifact_bytes)
@@ -134,34 +137,24 @@ def main():
     print(f"packed artifact: {vision_artifact_bytes(qnet):,} bytes, "
           f"per-layer bits {qnet.layer_bits()}")
 
-    engine = VisionEngine(qnet, batch_size=args.batch, mesh=mesh,
-                          backend=args.backend)
+    sched = Scheduler(VisionAdapter(qnet, mesh=mesh, backend=args.backend),
+                      args.batch, mesh=mesh)
     if mesh is not None:
         print(f"mesh: data={mesh.shape['data']} model={mesh.shape['model']}"
               f" ({len(mesh.devices.flat)} devices)")
     dev = jax.devices()[0]
+    backends = {op: api.default_backend(op) for op in api.OPS}
     print(f"kernel backends on {dev.platform}:{dev.device_kind}: "
-          f"{engine.kernel_backends()}")
+          f"{backends}")
     images = rng.uniform(0, 1, size=(
         args.requests, *cfg.in_hw, cfg.in_ch)).astype(np.float32)
     with obs.span("serve.generate", cat="serve", requests=len(images),
                   batch=args.batch):
-        logits = engine.run(images)
+        logits = np.stack(sched.serve(list(images)))
     preds = logits.argmax(-1)
-    print(f"served {len(images)} images in waves of {args.batch}: "
+    print(f"served {len(images)} images over {args.batch} slots: "
           f"preds {preds.tolist()}")
-    rep = engine.utilization_report()
-    lat = rep["latency_us"]
-    if lat is not None:
-        qd = rep["queue_depth"]
-        print(f"wave latency: p50={lat['p50'] / 1e3:.1f}ms "
-              f"p95={lat['p95'] / 1e3:.1f}ms p99={lat['p99'] / 1e3:.1f}ms "
-              f"over {lat['waves']} wave(s); queue depth mean "
-              f"{qd['mean']:.1f} max {qd['max']}")
-    if mesh is not None:
-        print(f"utilization: mean {rep['mean_util']:.3f} over "
-              f"{rep['waves']} waves, per-device "
-              f"{[round(u, 3) for u in rep['per_device']]}")
+    print_serving_report(sched, mesh)
     trace_path = obs.export_if_configured("vision_trace.json")
     if trace_path:
         print(f"trace -> {trace_path} (render: python -m repro.obs.report)")

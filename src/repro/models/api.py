@@ -69,8 +69,10 @@ class Model:
         return self._fns[3](self.cfg, batch, max_len, dtype)
 
     def prefill(self, params, batch):
-        """Full forward over the prompt; returns last-position logits.
-        (Cache population from prefill KV is handled in serve/engine.py.)"""
+        """Full forward over the prompt; returns last-position logits and
+        the per-layer KV. Nothing fills a decode cache from this KV: the
+        serving runtime feeds prompts through `decode`, one position a
+        step (`repro.serve.runtime.adapters.LMDecodeAdapter`)."""
         logits, _, kvs = self._fns[1](
             params, batch["tokens"], self.cfg,
             src_embed=batch.get("src_embed"), collect_kv=True)

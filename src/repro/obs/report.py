@@ -17,9 +17,7 @@ REPRO_OBS_TRACE=trace.json``) and prints
   backend×pipeline histogram.
 * **Top spans** — where the wall-clock went, by total span duration.
 * **Serving runtime** — the scheduler's admission/eviction/page counters
-  and `serve.step` span aggregate when the trace contains serving work,
-  plus a policy-comparison table from ``BENCH_serving.json``
-  (benchmarks/loadgen) when that artifact sits next to the trace.
+  and `serve.step` span aggregate when the trace contains serving work.
 
 The path defaults to ``REPRO_OBS_TRACE`` then ``BENCH_trace.json``.
 Dependency-free (stdlib only): runs anywhere the JSON artifact lands,
@@ -140,30 +138,6 @@ def serving_summary(doc: Dict[str, Any]) -> Dict[str, Any]:
     return {"counters": serve, "steps": span}
 
 
-def render_serving_bench(payload: Dict[str, Any]) -> str:
-    """Render a BENCH_serving.json (benchmarks/loadgen) policy table."""
-    out = ["== serving benchmark (BENCH_serving.json) =="]
-    w = payload.get("workload", {})
-    out.append(f"  workload: {w.get('requests')} requests @ "
-               f"{w.get('qps')} req/s, {w.get('slots')} slots, "
-               f"seed {w.get('seed')}")
-    out.append(_fmt_table(
-        ["policy", "req/s", "tok/s", "p50_s", "p99_s", "steps",
-         "occupancy", "max_queue"],
-        [[r["policy"], f"{r['throughput_rps']:.3f}",
-          f"{r['throughput_tps']:.3f}", f"{r['latency_s']['p50']:.1f}",
-          f"{r['latency_s']['p99']:.1f}", str(r["steps"]),
-          f"{r['occupancy']['mean']:.0%}",
-          str(r["queue_depth"]["max"])]
-         for r in payload.get("rows", [])]))
-    acc = payload.get("acceptance", {})
-    if acc:
-        out.append(f"  continuous vs wave: "
-                   f"{acc.get('throughput_gain'):.2f}x throughput, "
-                   f"{acc.get('p99_ratio'):.2f}x p99 latency")
-    return "\n".join(out)
-
-
 def render(doc: Dict[str, Any]) -> str:
     out = []
     rows = mac_table(doc)
@@ -228,9 +202,6 @@ def main(argv=None) -> int:
                          "or BENCH_trace.json)")
     ap.add_argument("--top", type=int, default=10,
                     help="span rows to show")
-    ap.add_argument("--serving", default="BENCH_serving.json",
-                    help="serving benchmark artifact to summarize when "
-                         "present (benchmarks/loadgen)")
     args = ap.parse_args(argv)
     try:
         doc = load_trace(args.trace)
@@ -240,12 +211,6 @@ def main(argv=None) -> int:
     print(f"trace: {args.trace} "
           f"({len(doc.get('traceEvents', []))} events)")
     print(render(doc))
-    try:
-        with open(args.serving) as fh:
-            print()
-            print(render_serving_bench(json.load(fh)))
-    except OSError:
-        pass  # no serving artifact around — trace-only report
     return 0
 
 

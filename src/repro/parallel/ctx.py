@@ -3,8 +3,8 @@
 **Paper analogy (XpulpNN §V):** an active mesh is the paper's parallel
 cluster — one JAX device per cluster core. `make_mesh` is the repo-wide
 way to build that cluster, and `jax.set_mesh` enters it; everything
-layered above (`repro.kernels.api.qdot_sharded`, the serve engine's wave
-sharding, the GSPMD constraints below) assumes it. Packed sub-byte arrays
+layered above (`repro.kernels.api.qdot_sharded`, the serving runtime's
+data-parallel slot sharding, the GSPMD constraints below) assumes it. Packed sub-byte arrays
 inside the context obey the invariants in `repro.parallel.sharding`:
 sharded only on the output-feature axis, never on the packed reduction
 axis (a shard boundary inside a CHUNK group would split int8 containers

@@ -2,21 +2,19 @@
 
 One scheduler + slot manager + stats stack serves every workload through
 a pluggable adapter (`adapters.WorkloadAdapter`): LM token decode and
-quantized-CNN image classification ship here; the legacy wave engines in
-`repro.serve.engine` are thin compat wrappers over this package.
+quantized-CNN image classification ship here. The launch CLIs, the
+examples and the chip benchmark all serve through this one loop.
 
 The design mirrors the paper's cluster-utilization argument at request
-granularity: a synchronous wave keeps "cores" (slots) idle behind the
-wave's straggler exactly like an unbalanced im2col split idles cluster
-cores; continuous batching re-admits queued requests into freed slots
-mid-wave so the slot array — and with ``mesh=`` every data-parallel
-device behind it — stays busy.
+granularity: a slot that idles behind a straggler is an idle cluster
+core, so the scheduler re-admits queued requests into freed slots at
+the next step and the slot array — and with ``mesh=`` every
+data-parallel device behind it — stays busy.
 """
 from repro.serve.runtime.adapters import (LMDecodeAdapter, Request,
                                           VisionAdapter, WorkloadAdapter)
-from repro.serve.runtime.scheduler import (Backpressure, Scheduler,
-                                           WaveStats)
+from repro.serve.runtime.scheduler import Backpressure, Scheduler
 from repro.serve.runtime.slots import SlotManager
 
 __all__ = ["Backpressure", "LMDecodeAdapter", "Request", "Scheduler",
-           "SlotManager", "VisionAdapter", "WaveStats", "WorkloadAdapter"]
+           "SlotManager", "VisionAdapter", "WorkloadAdapter"]

@@ -13,11 +13,11 @@ and what one engine step computes). An adapter provides:
 * **prefill/step** — ``step(state, feed, positions)`` runs one engine
   step over all physical slots and returns per-slot host outputs. The
   runtime is token-synchronous: LM prefill is the same step fed prompt
-  tokens (exactly what the wave engine's replay prefill lowered to), so
-  one jitted callable serves both phases at one compiled shape.
+  tokens, one position per step, so one jitted callable serves both
+  phases at one compiled shape.
 * **request cursor** — ``begin`` wraps a payload into a cursor,
   ``feed``/``consume`` drive it one step at a time, and ``consume``'s
-  return value is the **finished predicate** (mid-wave eviction point).
+  return value is the **finished predicate** (the eviction point).
 
 With `repro.obs` on, the LM and vision steps split into three spans
 each (``lm.*`` / ``vision.*``): ``dispatch`` (inputs to the device and
@@ -37,8 +37,8 @@ before the wait.
 
 Per-request bit-exactness invariant: every adapter's step must be
 row-independent (slot *i*'s outputs depend only on slot *i*'s feeds),
-which is what makes continuous batching bit-exact vs synchronous waves
-regardless of admission order. The vector-position decode path
+which is what makes per-request outputs bit-exact regardless of slot
+count, admission order and mesh. The vector-position decode path
 (`repro.nn.attention.attn_decode`) preserves this by construction.
 """
 from __future__ import annotations
@@ -58,8 +58,7 @@ STATE_RESET_KEYS = ("ssm", "rec", "conv")
 
 @dataclasses.dataclass
 class Request:
-    """One LM generation request (public serving API; re-exported by
-    `repro.serve.engine` for compatibility)."""
+    """One LM generation request (public serving API)."""
     prompt: np.ndarray          # (S,) int32
     max_new_tokens: int = 32
     out: Optional[np.ndarray] = None
@@ -150,15 +149,13 @@ class LMDecodeAdapter(WorkloadAdapter):
     Prefill and decode are the same jitted ``decode`` call
     (``model.decode`` and the greedy pick below) with a per-slot position
     vector: a slot working through its prompt is fed prompt tokens
-    (outputs ignored until the last prompt position — the wave engine's
-    replay-prefill, now per slot), then generated tokens.
-    An all-equal position vector is bit-exact vs the scalar-index wave
-    path, so per-request outputs are identical to `Engine.generate`'s.
+    (outputs ignored until the last prompt position), then generated
+    tokens.
 
-    Per-request semantics (cohort-independent, unlike the old ragged
-    wave prefill which let a short prompt attend to pad tokens): output
-    k exists iff ``k < max_new_tokens`` and ``prompt_len + k < max_len``
-    and no earlier EOS; the EOS token itself is emitted (wave parity).
+    Per-request semantics (independent of the other requests in the
+    slot array): output k exists iff ``k < max_new_tokens`` and
+    ``prompt_len + k < max_len`` and no earlier EOS; the EOS token
+    itself is emitted.
     Non-greedy sampling draws from a per-request generator seeded
     ``(seed, rid)`` so outputs stay admission-order invariant.
 
@@ -210,7 +207,7 @@ class LMDecodeAdapter(WorkloadAdapter):
         self._decode = jax.jit(decode,
                                donate_argnums=(1,) if donate_state else ())
 
-    # ---- placement (same layout as the wave engine) ----
+    # ---- placement (slot rows in contiguous blocks over dp_axis) ----
 
     def _put_wave(self, arr):
         import jax
@@ -297,8 +294,7 @@ class LMDecodeAdapter(WorkloadAdapter):
               seed: int = 0):
         prompt = np.asarray(payload.prompt, np.int32).reshape(-1)
         if prompt.size == 0:
-            # zero-length prompt: pad to a single BOS(=0) token, the
-            # wave engines' filler convention
+            # zero-length prompt: pad to a single BOS(=0) token
             prompt = np.zeros((1,), np.int32)
         max_new = int(payload.max_new_tokens)
         cur = _LMCursor(
@@ -370,12 +366,11 @@ class VisionAdapter(WorkloadAdapter):
     """Stateless quantized-CNN classification: a request is one image,
     one engine step is one batched integer forward, and every admitted
     request finishes after exactly one step (admission is the only
-    scheduling decision, so continuous batching == don't wait for a full
-    wave). `begin` keeps the float32 image as it came and touches no
-    device; the step's one jitted program quantizes the whole feed with
-    the net's input spec (one elementwise op beside the convs, on each
-    chip's shard under ``mesh=``), then runs the integer forward. Pad
-    slots are zeros, which quantize to 0.
+    scheduling decision). `begin` keeps the float32 image as it came and
+    touches no device; the step's one jitted program quantizes the whole
+    feed with the net's input spec (one elementwise op beside the convs,
+    on each chip's shard under ``mesh=``), then runs the integer
+    forward. Pad slots are zeros, which quantize to 0.
     """
 
     name = "vision"

@@ -1,27 +1,22 @@
 """Event-driven serving scheduler: admission queue, per-slot occupancy,
-mid-wave eviction.
+eviction as soon as a request finishes.
 
 The loop is token-synchronous: every `step()` runs the adapter's jitted
 engine step once over the full physical slot array, feeds each occupied
 slot its next input (prompt token, generated token, or image), folds the
 per-slot outputs back into the request cursors, and **evicts finished
-slots immediately** — under the default ``policy="continuous"`` the
-freed slot is re-admitted from the queue at the very next step, so a
-long request never holds the whole batch hostage (Orca-style iteration-
-level scheduling). ``policy="wave"`` only admits when *all* slots are
-free, which reproduces the legacy synchronous wave engines — same
-per-request outputs, same `utilization_report()` — and is the baseline
-the serving benchmark compares against.
+slots immediately**: the freed slot is re-admitted from the queue at the
+very next step, so a long request never holds the whole batch hostage
+(Orca-style iteration-level scheduling).
 
-Timestamps are injected (``submit(x, now=...)`` / ``step(now=...)``) so
-the load generator can drive a deterministic virtual clock; when omitted
-they fall back to ``self.clock`` (wall time). Latency is measured
-submit→finish in the caller's time unit.
+Timestamps may be injected (``submit(x, now=...)`` / ``step(now=...)``);
+when omitted they fall back to ``self.clock`` (wall time). Latency is
+measured submit→finish in the caller's time unit.
 
 Because every adapter step is row-independent and sampling is keyed per
-request, per-request outputs are **bit-exact across policies, admission
-orders, and slot placements** — continuous batching changes *when* a
-request runs, never *what* it computes.
+request, per-request outputs are **bit-exact across slot counts,
+admission orders, slot placements and meshes**: batching changes *when*
+a request runs, never *what* it computes.
 
 With `repro.obs` on, each phase records a ``cat="serve"`` span:
 ``serve.submit`` (arg ``rid``: the adapter's ``begin`` and the fit
@@ -50,77 +45,6 @@ class Backpressure(RuntimeError):
     """Admission queue is full; retry after requests drain."""
 
 
-class WaveStats:
-    """Per-wave per-device slot utilization + latency bookkeeping (the
-    legacy engines' `_WaveStats`, hoisted here so the runtime and the
-    compat shims share one implementation): device d owns the contiguous
-    slot range [d*B/dp, (d+1)*B/dp); real slots fill from 0, so a padded
-    slot is an idle cluster core (the paper's fig. 9 readout).
-
-    Each wave records its latency (stamped by ``clock``, an instance-
-    overridable callable so tests inject a deterministic fake) and the
-    request-queue depth at admission; `utilization_report()` aggregates
-    p50/p95/p99 latency and queue-depth stats next to the utilization
-    columns."""
-
-    batch: int
-    _dp: int
-    clock = staticmethod(time.perf_counter)   # seconds; override in tests
-
-    def __init__(self, batch: int = 0, dp: int = 1):
-        self.batch = batch
-        self._dp = dp
-        self.wave_stats: List[dict] = []
-
-    def _record_wave(self, n_real: int, queue_depth: int = 0):
-        b_loc = self.batch // self._dp
-        per_dev = [min(max(n_real - d * b_loc, 0), b_loc) / b_loc
-                   for d in range(self._dp)]
-        self.wave_stats.append({"n_real": n_real, "batch": self.batch,
-                                "per_device": per_dev,
-                                "queue_depth": queue_depth,
-                                "t0": self.clock(), "latency_us": None})
-
-    def _finish_wave(self):
-        w = self.wave_stats[-1]
-        w["latency_us"] = (self.clock() - w.pop("t0")) * 1e6
-        obs.counter("engine.waves").add(1)
-        obs.counter("engine.requests").add(w["n_real"])
-        return w
-
-    def utilization_report(self) -> dict:
-        """Aggregate per-device slot utilization, wave-latency
-        percentiles, and queue-depth stats across the waves served so
-        far — a device whose slots were padding did no useful work."""
-        if not self.wave_stats:
-            return {"devices": self._dp, "waves": 0, "mean_util": 0.0,
-                    "per_device": [0.0] * self._dp, "latency_us": None,
-                    "queue_depth": None, "occupancy_timeline": []}
-        per_dev = [float(np.mean([w["per_device"][d]
-                                  for w in self.wave_stats]))
-                   for d in range(self._dp)]
-        lats = [w["latency_us"] for w in self.wave_stats
-                if w.get("latency_us") is not None]
-        latency = None
-        if lats:
-            latency = {"p50": float(np.percentile(lats, 50)),
-                       "p95": float(np.percentile(lats, 95)),
-                       "p99": float(np.percentile(lats, 99)),
-                       "mean": float(np.mean(lats)),
-                       "max": float(np.max(lats)),
-                       "waves": len(lats)}
-        depths = [w.get("queue_depth", 0) for w in self.wave_stats]
-        return {"devices": self._dp, "waves": len(self.wave_stats),
-                "mean_util": float(np.mean(per_dev)),
-                "per_device": per_dev,
-                "latency_us": latency,
-                "queue_depth": {"mean": float(np.mean(depths)),
-                                "max": int(np.max(depths))},
-                # per-device real-slot occupancy over time, wave by wave
-                "occupancy_timeline": [list(w["per_device"])
-                                       for w in self.wave_stats]}
-
-
 @dataclasses.dataclass
 class _Entry:
     """One submitted request's lifecycle record."""
@@ -133,22 +57,25 @@ class _Entry:
     submit_us: Optional[float] = None    # spans' clock, when obs is on
 
 
-class Scheduler(WaveStats):
+class Scheduler:
     """Workload-agnostic serving loop over a `WorkloadAdapter`.
 
-    Parameters: ``num_slots`` is the number of *real* request slots (the
-    legacy engines' ``batch_size``); with ``mesh=`` the physical slot
-    array is padded to the data-parallel axis size and sharded so device
-    *d* owns a contiguous block (ragged ``num_slots % dp`` is absorbed
-    by pad slots that are never admitted — the old hard divisibility
-    constraint is gone). ``max_queue`` bounds the admission queue:
-    `submit` raises `Backpressure` when it is full.
+    Parameters: ``num_slots`` is the number of *real* request slots; with
+    ``mesh=`` the physical slot array is padded to the data-parallel
+    axis size and sharded so device *d* owns a contiguous block (ragged
+    ``num_slots % dp`` is absorbed by pad slots that are never
+    admitted). ``max_queue`` bounds the admission queue: `submit` raises
+    `Backpressure` when it is full. ``policy`` accepts only
+    ``"continuous"``; it stays a keyword because the chip benchmark
+    passes it.
     """
+
+    clock = staticmethod(time.perf_counter)   # seconds; override in tests
 
     def __init__(self, adapter, num_slots: int, *, mesh=None,
                  dp_axis: str = "data", policy: str = "continuous",
                  max_queue: Optional[int] = None, page_tokens: int = 16):
-        if policy not in ("continuous", "wave"):
+        if policy != "continuous":
             raise ValueError(f"unknown policy {policy!r}")
         if mesh is not None:
             from repro.parallel.sharding import cluster_axis_size
@@ -156,13 +83,10 @@ class Scheduler(WaveStats):
         else:
             dp = 1
         self.adapter = adapter
-        self.policy = policy
+        self._dp = dp
         self.max_queue = max_queue
         self.slots = SlotManager(num_slots, adapter.max_len, dp=dp,
                                  page_tokens=page_tokens)
-        # wave stats run over the *physical* array so per-device columns
-        # line up with the mesh blocks even when num_slots % dp != 0
-        super().__init__(batch=self.slots.phys, dp=dp)
         self.state = adapter.init_state(self.slots.phys)
         self._queue: Deque[_Entry] = collections.deque()
         self._entries: Dict[int, _Entry] = {}
@@ -173,7 +97,6 @@ class Scheduler(WaveStats):
         self._rid0 = 0              # sampling-key base of the live serve()
         self._greedy = True
         self._seed = 0
-        self._wave_live = 0
 
     # ------------------------------------------------------- admission ---
 
@@ -212,18 +135,8 @@ class Scheduler(WaveStats):
 
     def _admit(self, now: float):
         admitted = []
-        if self.policy == "wave":
-            # legacy semantics: only admit when the whole array is free
-            if self.slots.active or not self._queue:
-                return
-            n = min(self.slots.real, len(self._queue))
-            for _ in range(n):
-                admitted.append(self._admit_one(now))
-            self._wave_live = n
-            self._record_wave(n, queue_depth=len(self._queue))
-        else:
-            while self._queue and self.slots.free_slots:
-                admitted.append(self._admit_one(now))
+        while self._queue and self.slots.free_slots:
+            admitted.append(self._admit_one(now))
         if admitted:
             mask = np.zeros(self.slots.phys, bool)
             mask[[e.sid for e in admitted]] = True
@@ -265,6 +178,9 @@ class Scheduler(WaveStats):
         finished: List[int] = []
         with obs.span("serve.consume", cat="serve"):
             self.state = state          # releases the previous step's state
+            # the slots that ran this step, read before any is evicted
+            occupancy = self.slots.occupancy()
+            per_device = self.slots.device_occupancy()
             for s in active:
                 e = self._entries[s.rid]
                 self.slots.advance(s.sid, int(pos[s.sid]) + 1)
@@ -274,8 +190,7 @@ class Scheduler(WaveStats):
             self.step_log.append({
                 "t": now, "active": len(active),
                 "queue_depth": len(self._queue),
-                "occupancy": self.slots.occupancy(),
-                "per_device": self.slots.device_occupancy()})
+                "occupancy": occupancy, "per_device": per_device})
         return finished
 
     def _finish(self, e: _Entry, now: float):
@@ -289,19 +204,13 @@ class Scheduler(WaveStats):
             "finish_t": now,
             "prompt_len": self.adapter.prompt_len(e.cursor),
             "tokens_out": self.adapter.tokens_out(e.cursor)})
-        if self.policy == "wave":
-            if e.sid is not None:
-                self._wave_live -= 1
-                if self._wave_live == 0:
-                    self._finish_wave()
-        else:
-            obs.counter("engine.requests").add(1)
+        obs.counter("engine.requests").add(1)
 
     # ------------------------------------------------ batch convenience ---
 
     def serve(self, payloads, greedy: bool = True, seed: int = 0) -> list:
         """Submit everything, run to drain, return per-request results in
-        submission order (the synchronous `Engine.generate` shape)."""
+        submission order."""
         self._greedy, self._seed = greedy, seed
         self._rid0 = self._next_rid
         rids = [self.submit(p) for p in payloads]
@@ -316,9 +225,8 @@ class Scheduler(WaveStats):
     # ---------------------------------------------------------- report ---
 
     def serving_report(self) -> dict:
-        """Request-granular latency/occupancy stats (the continuous-
-        batching analogue of `utilization_report`, which is wave-
-        granular). Time unit is whatever the caller's clock used."""
+        """Request-granular latency/occupancy stats. Time unit is
+        whatever the caller's clock used."""
         lats = [r["finish_t"] - r["submit_t"] for r in self.request_log]
         lat = None
         if lats:
@@ -330,7 +238,6 @@ class Scheduler(WaveStats):
         depths = [s["queue_depth"] for s in self.step_log]
         occ = [s["occupancy"] for s in self.step_log]
         return {
-            "policy": self.policy,
             "slots": self.slots.real,
             "devices": self._dp,
             "requests": len(self.request_log),

@@ -11,11 +11,10 @@ reserved-vs-used gap is the fragmentation a true shared-pool paged cache
 (vLLM/MaxText page_manager style) would reclaim. The physical backing
 here is still dense per slot — (slots, max_len, ...) arrays, page
 accounting is bookkeeping + admission control, not indirection — which
-keeps the decode step a plain batched call and bit-exact vs the wave
-engines.
+keeps the decode step a plain batched call.
 
-Ragged data-parallel meshes are absorbed here (the old engines' hard
-``batch % dp == 0`` constraint): the physical slot count is padded up to
+Ragged data-parallel meshes are absorbed here (``num_slots % dp`` need
+not be 0): the physical slot count is padded up to
 the next multiple of ``dp`` and the pad slots are never admitted, so
 device *d* always owns the whole contiguous physical range
 ``[d*block, (d+1)*block)`` and real results are sliced back by slot id.
@@ -77,7 +76,7 @@ class SlotManager:
 
     def check_fits(self, prompt_len: int):
         """Admission control: a prompt longer than the cache can never be
-        served (the old wave engines silently clamped the cache write)."""
+        served, so it is refused rather than clamped into the cache."""
         if prompt_len > self.max_len:
             raise CapacityError(
                 f"prompt length {prompt_len} exceeds max_len="

@@ -57,7 +57,7 @@ TRACE_OK = {
     "displayTimeUnit": "ms",
     "repro": {
         "version": 1,
-        "counters": {"engine.waves": 2},
+        "counters": {"engine.requests": 2},
         "op_counters": {
             "qdot|w4a8|pallas_interpret|off": {
                 "calls": 3, "macs": 3145728, "logical_bytes": 135168,
@@ -93,28 +93,6 @@ E2E_OK = {
          "speedup": 1.8, "efficiency": 0.9, "bytes_streamed": 91032,
          "proj_us_v5e": 4.1},
     ],
-}
-
-
-def _serving_row(policy, tps, p99):
-    return {"policy": policy, "requests": 24, "steps": 80,
-            "tokens_out": 150, "makespan_s": 70.0,
-            "throughput_rps": 24 / 70.0, "throughput_tps": tps,
-            "latency_s": {"p50": 12.0, "p95": 20.0, "p99": p99,
-                          "mean": 13.5, "max": 30.0},
-            "queue_depth": {"mean": 1.2, "max": 6},
-            "occupancy": {"mean": 0.8, "min": 0.0}}
-
-
-SERVING_OK = {
-    "version": 1,
-    "workload": {"model": "qwen2p5-3b-smoke", "requests": 24, "qps": 0.6,
-                 "step_cost_s": 1.0, "slots": 4, "max_len": 32,
-                 "prompt_lens": [2, 6], "max_new": [1, 12], "seed": 0,
-                 "devices": 1},
-    "rows": [_serving_row("wave", 1.8, 48.0),
-             _serving_row("continuous", 2.4, 24.0)],
-    "acceptance": {"throughput_gain": 2.4 / 1.8, "p99_ratio": 0.5},
 }
 
 
@@ -220,41 +198,6 @@ def test_e2e_fixture_valid():
 def test_e2e_rejects(mutate, match):
     with pytest.raises(SchemaError, match=match):
         schema.validate_e2e(_mutated(E2E_OK, mutate))
-
-
-# ------------------------------------------------------------- serving ---
-
-def test_serving_fixture_valid():
-    schema.validate_serving(SERVING_OK)
-
-
-@pytest.mark.parametrize("mutate,match", [
-    (lambda p: p.update(version=2), "out of range"),
-    (lambda p: p["workload"].pop("qps"), "missing required field 'qps'"),
-    (lambda p: p["workload"].update(slots=0), "out of range"),
-    (lambda p: p.update(rows=[]), "empty rows"),
-    (lambda p: p["rows"][0].update(policy="batch"), "out of range"),
-    (lambda p: p.update(rows=[_serving_row("continuous", 2.4, 24.0)]),
-     "missing policy row 'wave'"),
-    (lambda p: p["rows"][0]["latency_s"].pop("p99"),
-     "missing required field 'p99'"),
-    (lambda p: p["rows"][0]["occupancy"].update(mean=1.5),
-     "out of range"),
-    (lambda p: p["rows"][0]["queue_depth"].update(max=2.5), "expected"),
-    (lambda p: p.pop("acceptance"), "missing required field"),
-    # the acceptance ordering itself is enforced, fig8-roofline style:
-    # continuous must strictly beat the wave baseline both ways
-    (lambda p: p["rows"][1].update(throughput_tps=1.0),
-     "does not beat the wave baseline on token throughput"),
-    (lambda p: p["rows"][1]["latency_s"].update(p99=60.0),
-     "does not beat the wave baseline on p99"),
-    (lambda p: p["acceptance"].update(throughput_gain=0.9),
-     "token throughput"),
-    (lambda p: p["acceptance"].update(p99_ratio=1.1), "p99"),
-])
-def test_serving_rejects(mutate, match):
-    with pytest.raises(SchemaError, match=match):
-        schema.validate_serving(_mutated(SERVING_OK, mutate))
 
 
 # ------------------------------------------------------------ accuracy ---
@@ -399,7 +342,6 @@ def test_validate_file_dispatch(tmp_path):
     for name, payload in (("BENCH_kernels.json", KERNELS_OK),
                           ("BENCH_cluster.json", CLUSTER_OK),
                           ("BENCH_e2e.json", E2E_OK),
-                          ("BENCH_serving.json", SERVING_OK),
                           ("BENCH_accuracy.json", ACCURACY_OK),
                           ("BENCH_trace.json", TRACE_OK)):
         f = tmp_path / name

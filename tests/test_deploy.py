@@ -18,7 +18,7 @@ from repro.deploy.policy import (PlanRule, PrecisionPlan, load_plan,
 from repro.launch.convert import artifact_bytes, convert_params
 from repro.models.api import Model, build
 from repro.nn.layers import QuantConfig, dense_apply
-from repro.serve.engine import Engine, Request
+from repro.serve.runtime import LMDecodeAdapter, Request, Scheduler
 
 QINT = QuantConfig(mode="int", w_bits=8, a_bits=8)
 
@@ -276,17 +276,17 @@ def test_engine_serves_mixed_plan():
     fp, q = _smoke_models(plan)
     fp_params = fp.init(jax.random.PRNGKey(0))
     q_params = apply_plan(q.init(jax.random.PRNGKey(0)), fp_params, plan)
-    eng = Engine(q, q_params, batch_size=2, max_len=32, plan=plan)
+    adapter = LMDecodeAdapter(q, q_params, 32, plan=plan)
     reqs = [Request(prompt=np.array([3, 5, 7], np.int32), max_new_tokens=4),
             Request(prompt=np.array([11, 2], np.int32), max_new_tokens=4),
             Request(prompt=np.array([9], np.int32), max_new_tokens=4)]
-    out = eng.generate(reqs)
+    out = Scheduler(adapter, 2).serve(reqs)
     assert len(out) == 3
     for r in out:
         assert r.out is not None and 1 <= len(r.out) <= 4
         assert (r.out >= 0).all() and (r.out < fp.cfg.vocab).all()
-    assert eng.plan is plan
-    assert eng.artifact_bytes() == artifact_bytes(q_params)
+    assert adapter.plan is plan
+    assert artifact_bytes(adapter.params) == artifact_bytes(q_params)
 
 
 def test_end_to_end_calibrate_plan_pack(rng):

@@ -38,16 +38,18 @@ def test_serve_cli():
 
 
 def test_serve_cli_mesh():
-    """--mesh dp,tp serves on a forced-host-device cluster and prints the
-    per-device utilization report."""
+    """--mesh dp,tp serves on a forced-host-device cluster and prints
+    each device's mean slot occupancy over the serve's steps."""
     r = _run(["repro.launch.serve", "--arch", "qwen2.5-3b", "--smoke",
               "--quant", "w4a8", "--requests", "6", "--batch", "4",
               "--max-new", "4", "--mesh", "4,2"],
              extra_env={"XLA_FLAGS":
                         "--xla_force_host_platform_device_count=8"})
     assert "mesh: data=4 model=2" in r.stdout, r.stderr[-1500:]
-    assert "cluster utilization" in r.stdout
-    assert "d3=50%" in r.stdout  # wave 2 carries 2 real of 4 slots
+    # one slot per device; the seed-0 prompts (2-7 tokens) and 4 new
+    # tokens each keep device 2 busy in all 17 steps of the serve
+    assert "per-device occupancy: mean 71% over 17 step(s)" in r.stdout
+    assert "[d0=59% d1=76% d2=100% d3=47%]" in r.stdout
 
 
 def test_fig9_cluster_bench_cli(tmp_path):

@@ -11,7 +11,7 @@
   same tokens, greedy and sampled (the rows gathered from the mesh);
 * a greedy step copies ``4 x phys_slots`` bytes and counts no host
   sampling step; a sampled serve counts every step it takes and replays
-  across runs and policies;
+  across runs and slot counts;
 * the step is one program, ``jit_decode``, whose first output is the
   (B,) int32 tokens.
 """
@@ -160,8 +160,7 @@ def test_sampled_serve_takes_the_host_path_and_replays(lm):
         steps * 2 * padded_vocab(cfg.vocab) * last.dtype.itemsize
     obs.disable()
     b = Scheduler(_adapter(lm), 2).serve(mk(), greedy=False, seed=7)
-    c = Scheduler(_adapter(lm), 3, policy="wave").serve(
-        mk(), greedy=False, seed=7)
+    c = Scheduler(_adapter(lm), 3).serve(mk(), greedy=False, seed=7)
     assert _outs(a) == _outs(b) == _outs(c)
 
 

@@ -9,7 +9,7 @@ counters, the dispatch decision log, Chrome-trace export, env knobs.
 * chrome_trace() round-trips through json and passes the checked-in
   artifact validator (benchmarks/schema.py::check_trace);
 * disabled mode records nothing — the backend-parity invariant;
-* engine wave-latency percentiles against a deterministic fake clock;
+* the scheduler's request-latency percentiles on injected timestamps;
 * the shared timer dedupe (tune._time == obs.time_call / 1e6) and the
   env-knob registry (validation, legacy alias, unknown-var warning).
 """
@@ -321,67 +321,90 @@ def test_ring_buffer_bounds_memory():
     obs.enable(capacity=obs.DEFAULT_CAPACITY)
 
 
-# ------------------------------------------------------- engine latency ---
+# ----------------------------------------------------- serving latency ---
+
+def _stepping_scheduler(num_slots):
+    """A `Scheduler` over a host-only adapter whose request is the number
+    of steps it runs: no device, so only the loop's bookkeeping shows."""
+    from repro.serve.runtime import Scheduler, WorkloadAdapter
+
+    class Cursor:
+        def __init__(self, steps):
+            self.payload, self.left = steps, steps
+
+    class Steps(WorkloadAdapter):
+        name, max_len = "steps", 8
+
+        def input_spec(self):
+            return (1,), np.int32
+
+        def step(self, state, feed, positions):
+            return feed, state
+
+        def begin(self, payload, *, rid, greedy=True, seed=0):
+            return Cursor(payload)
+
+        def feed(self, cursor):
+            return np.zeros(1, np.int32), 0
+
+        def consume(self, cursor, row):
+            cursor.left -= 1
+            return cursor.left == 0
+
+        def tokens_out(self, cursor):
+            return cursor.payload
+
+    return Scheduler(Steps(), num_slots)
+
 
 def test_wave_latency_percentiles_fake_clock():
-    from repro.serve import engine
-
-    class Stats(engine._WaveStats):
-        def __init__(self, batch, dp):
-            self.batch, self._dp = batch, dp
-            self.wave_stats = []
-
-    st = Stats(batch=4, dp=2)
-    ticks = iter([0.0, 0.010, 1.0, 1.020, 2.0, 2.030, 3.0, 3.040])
-    st.clock = lambda: next(ticks)
-    for n_real, depth in ((4, 3), (4, 1), (3, 0), (1, 0)):
-        st._record_wave(n_real, queue_depth=depth)
-        w = st._finish_wave()
-        assert w["latency_us"] is not None
-    rep = st.utilization_report()
-    lat = rep["latency_us"]
-    want = [10e3, 20e3, 30e3, 40e3]
-    assert lat["waves"] == 4
-    assert lat["p50"] == pytest.approx(np.percentile(want, 50))
-    assert lat["p95"] == pytest.approx(np.percentile(want, 95))
-    assert lat["p99"] == pytest.approx(np.percentile(want, 99))
-    assert lat["mean"] == pytest.approx(25e3)
-    assert lat["max"] == pytest.approx(40e3)
-    assert rep["queue_depth"] == {"mean": 1.0, "max": 3}
-    assert rep["occupancy_timeline"] == [[1.0, 1.0], [1.0, 1.0],
-                                         [1.0, 0.5], [0.5, 0.0]]
+    """Request latency is finish minus submit on the caller's clock: the
+    report's percentiles, queue depths and occupancy are exact."""
+    sched = _stepping_scheduler(2)
+    for steps in (1, 2, 1):
+        sched.submit(steps, now=0.0)
+    sched.submit(1, now=0.015)
+    for t in (0.010, 0.020, 0.040):
+        sched.step(now=t)
+    assert sched.idle
+    rep = sched.serving_report()
+    lat = rep["latency"]
+    # r0 ends at step 1, r1 and r2 at step 2, r3 (submitted at 15 ms)
+    # waits for a slot until step 3
+    want = [0.010, 0.020, 0.020, 0.025]
+    for q in (50, 95, 99):
+        assert lat[f"p{q}"] == pytest.approx(np.percentile(want, q))
+    assert lat["mean"] == pytest.approx(np.mean(want))
+    assert lat["max"] == pytest.approx(0.025)
+    assert rep["queue_depth"] == {"mean": 1.0, "max": 2}
+    assert rep["occupancy"] == {"mean": pytest.approx(2.5 / 3),
+                                "min": 0.5}
+    assert [s["per_device"] for s in sched.step_log] == [[1.0], [1.0],
+                                                         [0.5]]
+    assert (rep["requests"], rep["steps"], rep["tokens_out"]) == (4, 3, 5)
 
 
 def test_wave_counters_bump_when_enabled():
-    from repro.serve import engine
-
-    class Stats(engine._WaveStats):
-        def __init__(self):
-            self.batch, self._dp = 2, 1
-            self.wave_stats = []
-
-    st = Stats()
+    sched = _stepping_scheduler(2)
+    for t in range(2):
+        sched.submit(1, now=float(t))
+    sched.drain()
+    assert obs.counter_values() == {}    # off: nothing is counted
     with obs.enabled_scope():
-        st._record_wave(2)
-        st._finish_wave()
-        st._record_wave(1)
-        st._finish_wave()
-    assert obs.counter_values() == {"engine.waves": 2,
-                                    "engine.requests": 3}
+        for t in range(3):
+            sched.submit(1, now=float(t))
+        sched.drain()
+    vals = obs.counter_values()
+    assert vals["engine.requests"] == 3
+    assert vals["serve.admits"] == 3 and vals["serve.evicts"] == 3
 
 
 def test_empty_report_has_null_latency():
-    from repro.serve import engine
-
-    class Stats(engine._WaveStats):
-        def __init__(self):
-            self.batch, self._dp = 2, 1
-            self.wave_stats = []
-
-    rep = Stats().utilization_report()
-    assert rep["latency_us"] is None
+    rep = _stepping_scheduler(2).serving_report()
+    assert rep["latency"] is None
     assert rep["queue_depth"] is None
-    assert rep["occupancy_timeline"] == []
+    assert rep["occupancy"] is None
+    assert (rep["requests"], rep["steps"], rep["tokens_out"]) == (0, 0, 0)
 
 
 # ----------------------------------------------------------- shared timer ---

@@ -4,30 +4,24 @@
   page reservation/used accounting, ragged-dp physical padding, capacity
   admission control, obs counters;
 * scheduler edge cases: backpressure on a bounded admission queue,
-  mid-wave eviction (a freed slot is re-admitted before the cohort
-  finishes — the tentpole behaviour), zero-length prompts,
-  max_new_tokens=0, drain on an empty queue;
+  eviction that refills a slot (a freed slot is re-admitted before the
+  other requests finish), zero-length prompts, max_new_tokens=0, drain
+  on an empty queue;
 * the bit-exactness invariant: per-request outputs identical across
-  policies (continuous == wave == legacy Engine), admission orders,
-  meshless vs dp-sharded (incl. ragged slots % dp), and greedy vs
-  per-request-seeded sampling;
-* engine-shim compat: `Engine`/`VisionEngine` wave stats and obs
-  counters match the legacy semantics;
-* the load generator: deterministic replay from a fixed seed and a
-  BENCH_serving.json that passes its schema with continuous batching
-  strictly beating the wave baseline.
+  slot counts and meshes (incl. ragged slots % dp), admission orders,
+  and greedy vs per-request-seeded sampling, for the LM and the CNN;
+* `serving_report`'s field invariants on real serves, and the
+  ``engine.requests`` counter;
+* the LM CLI in process: its tokens equal a direct serve, and with a
+  mesh it prints the per-device occupancy line.
 """
-import pathlib
+import dataclasses
 import sys
 
 import numpy as np
 import pytest
 
 import jax
-
-ROOT = pathlib.Path(__file__).resolve().parents[1]
-if str(ROOT) not in sys.path:  # `import benchmarks` from any rootdir
-    sys.path.insert(0, str(ROOT))
 
 from repro.configs.qwen2p5_3b import smoke_config
 from repro.models.api import build
@@ -52,8 +46,7 @@ def _adapter(lm, mesh=None, max_len=32):
 
 
 def _reqs(n=5, plen=2, max_new=3):
-    """Equal-length prompts (bit-comparable to the legacy wave prefill),
-    mixed generation budgets unless pinned."""
+    """Equal-length prompts, mixed generation budgets unless pinned."""
     return [Request(prompt=np.array([3 + i] + [5] * (plen - 1), np.int32),
                     max_new_tokens=(max_new if np.isscalar(max_new)
                                     else max_new[i]))
@@ -132,26 +125,20 @@ def test_backpressure_on_full_queue(lm):
 
 
 def test_mid_wave_eviction_refills_slot(lm):
-    """The tentpole behaviour: with 2 slots and 3 requests, the third
-    request must be admitted the moment the short first request frees
-    its slot — strictly before the long second request finishes. The
-    wave policy on the same workload must instead hold it back until
-    the whole cohort drains."""
-    reqs = _reqs(3, max_new=[1, 6, 6])
+    """With 2 slots and 3 requests, the third request must be admitted
+    the moment the short first request frees its slot — strictly before
+    the long second request finishes."""
     sched = Scheduler(_adapter(lm), 2, policy="continuous")
-    sched.serve([Request(prompt=r.prompt.copy(),
-                         max_new_tokens=r.max_new_tokens) for r in reqs])
+    sched.serve(_reqs(3, max_new=[1, 6, 6]))
     log = {r["rid"]: r for r in sched.request_log}
-    assert log[2]["admit_t"] < log[1]["finish_t"]   # mid-wave admission
+    assert log[2]["admit_t"] < log[1]["finish_t"]   # mid-serve admission
     assert log[2]["admit_t"] >= log[0]["finish_t"]  # into slot 0's grave
 
-    wave = Scheduler(_adapter(lm), 2, policy="wave")
-    wave.serve([Request(prompt=r.prompt.copy(),
-                        max_new_tokens=r.max_new_tokens) for r in reqs])
-    wlog = {r["rid"]: r for r in wave.request_log}
-    assert wlog[2]["admit_t"] >= wlog[1]["finish_t"]  # waits for cohort
-    # fewer engine steps for the same work is the whole point
-    assert sched.serving_report()["steps"] < wave.serving_report()["steps"]
+
+def test_policy_accepts_only_continuous(lm):
+    for name in ("wave", "static"):
+        with pytest.raises(ValueError, match=f"unknown policy '{name}'"):
+            Scheduler(_adapter(lm), 2, policy=name)
 
 
 def test_degenerate_requests(lm):
@@ -184,26 +171,33 @@ def test_drain_on_empty_queue_is_noop(lm):
 
 # ------------------------------------------------------- bit-exactness ---
 
-def test_policies_and_legacy_engine_bit_exact(lm):
-    """continuous == wave == legacy Engine per request (equal-length
-    prompts so the legacy pad-replaying prefill is comparable), and
-    ragged prompt lengths agree across the two runtime policies."""
-    from repro.serve.engine import Engine
+def _ragged_reqs():
+    """Ragged prompts (1 to 5 tokens) and mixed generation budgets."""
+    return [Request(prompt=np.arange(2, 3 + i, dtype=np.int32),
+                    max_new_tokens=m) for i, m in enumerate([1, 4, 2, 5, 3])]
 
-    _, model, params = lm
-    mixed = [1, 4, 2, 5, 3]
-    want = Engine(model, params, batch_size=4, max_len=32).generate(
-        _reqs(5, max_new=mixed))
-    for policy in ("wave", "continuous"):
-        got = Scheduler(_adapter(lm), 4, policy=policy).serve(
-            _reqs(5, max_new=mixed))
-        assert _outs(got) == _outs(want)
-    # ragged prompts: per-request outputs are batching-independent
-    rag = lambda: [Request(prompt=np.arange(2, 3 + i, dtype=np.int32),
-                           max_new_tokens=4) for i in range(5)]
-    a = Scheduler(_adapter(lm), 4, policy="wave").serve(rag())
-    b = Scheduler(_adapter(lm), 2, policy="continuous").serve(rag())
-    assert _outs(a) == _outs(b)
+
+def _dp4_mesh():
+    if len(jax.devices()) < 4:
+        pytest.skip("needs >=4 devices (XLA_FLAGS="
+                    "--xla_force_host_platform_device_count=8)")
+    return make_mesh((4, 1), ("data", "model"), devices=jax.devices()[:4])
+
+
+@pytest.fixture(scope="module")
+def one_slot_outs(lm):
+    return _outs(Scheduler(_adapter(lm), 1).serve(_ragged_reqs()))
+
+
+@pytest.mark.parametrize("dp", [None, 4])
+@pytest.mark.parametrize("num_slots", [1, 2, 3, 5])
+def test_outputs_independent_of_slots_and_mesh(lm, one_slot_outs,
+                                               num_slots, dp):
+    """Per-request outputs equal a one-slot meshless serve's, whatever
+    the slot count (ragged slots % dp included) and mesh."""
+    mesh = _dp4_mesh() if dp else None
+    sched = Scheduler(_adapter(lm, mesh=mesh), num_slots, mesh=mesh)
+    assert _outs(sched.serve(_ragged_reqs())) == one_slot_outs
 
 
 def test_admission_order_invariance(lm):
@@ -215,14 +209,11 @@ def test_admission_order_invariance(lm):
 
 def test_nongreedy_sampling_is_per_request(lm):
     """Sampled decoding draws from a per-request (seed, rid) generator,
-    so outputs replay across runs AND across policies — the legacy
-    shared-rng drew in wave order, which no admission-order-invariant
-    scheduler can reproduce."""
+    so outputs replay across runs AND across slot counts."""
     mk = lambda: _reqs(4, max_new=6)
     a = Scheduler(_adapter(lm), 2).serve(mk(), greedy=False, seed=7)
     b = Scheduler(_adapter(lm), 2).serve(mk(), greedy=False, seed=7)
-    c = Scheduler(_adapter(lm), 3, policy="wave").serve(
-        mk(), greedy=False, seed=7)
+    c = Scheduler(_adapter(lm), 3).serve(mk(), greedy=False, seed=7)
     assert _outs(a) == _outs(b) == _outs(c)
     # (the smoke model's softmax is near-degenerate, so different seeds
     # usually sample the argmax too — seed sensitivity is exercised at
@@ -266,34 +257,11 @@ def test_slot_state_reset_between_tenants(lm):
     assert got[1].out.tolist() == solo[0].out.tolist()
 
 
-# -------------------------------------------------------- engine shims ---
+# ------------------------------------------------------------- vision ---
 
-def test_engine_shim_stats_and_counters(lm):
-    from repro.serve.engine import Engine
-
-    _, model, params = lm
-    eng = Engine(model, params, batch_size=2, max_len=32)
-    obs.reset()
-    with obs.enabled_scope():
-        out = eng.generate(_reqs(5, max_new=2))
-        vals = obs.counter_values()
-    assert [len(r.out) for r in out] == [2] * 5
-    # 5 requests in waves of 2 -> 3 waves, legacy counter semantics
-    assert vals["engine.waves"] == 3 and vals["engine.requests"] == 5
-    assert vals["serve.admits"] == 5 and vals["serve.evicts"] == 5
-    rep = eng.utilization_report()
-    assert rep["waves"] == 3 and rep["devices"] == 1
-    assert rep["per_device"] == [pytest.approx((1 + 1 + 0.5) / 3)]
-    assert rep["latency_us"] is not None and rep["latency_us"]["waves"] == 3
-    assert rep["queue_depth"]["max"] == 3
-    # the runtime's request-granular report rides along on the shim
-    srep = eng.serving_report()
-    assert srep["requests"] == 5 and srep["policy"] == "wave"
-
-
-def test_vision_shim_matches_runtime(art=None):
+@pytest.fixture(scope="module")
+def vision_net():
     from repro.deploy.calibrate import calibrate_vision
-    from repro.serve.engine import VisionEngine
     from repro.vision.configs import get_vision_config
     from repro.vision.models import (forward_int, init_fp, quantize_input,
                                      quantize_net)
@@ -308,34 +276,102 @@ def test_vision_shim_matches_runtime(art=None):
         np.float32)
     want = np.asarray(forward_int(qnet, quantize_input(qnet, images),
                                   backend="xla"))
-    shim = VisionEngine(qnet, batch_size=2, backend="xla").run(images)
-    assert np.array_equal(shim, want)
-    cont = Scheduler(VisionAdapter(qnet, backend="xla"), 2).serve(
-        list(images))
-    assert np.array_equal(np.stack(cont), want)
-    empty = VisionEngine(qnet, batch_size=2, backend="xla").run(
-        np.zeros((0, *cfg.in_hw, cfg.in_ch), np.float32))
-    assert empty.shape == (0, cfg.num_classes)
+    return qnet, images, want
 
 
-# ----------------------------------------------------------- load gen ---
+@pytest.mark.parametrize("dp", [None, 4])
+@pytest.mark.parametrize("num_slots", [1, 3])
+def test_vision_outputs_independent_of_slots_and_mesh(vision_net,
+                                                      num_slots, dp):
+    """Served logits equal the batch forward's, whatever the slot count
+    and mesh; an empty serve returns nothing."""
+    qnet, images, want = vision_net
+    mesh = _dp4_mesh() if dp else None
+    sched = Scheduler(VisionAdapter(qnet, mesh=mesh, backend="xla"),
+                      num_slots, mesh=mesh)
+    assert np.array_equal(np.stack(sched.serve(list(images))), want)
+    assert sched.serve([]) == []
 
-def test_loadgen_deterministic_replay_and_schema(tmp_path):
-    """Same seed -> byte-identical BENCH_serving.json (virtual clock, no
-    wall time anywhere), the artifact passes its validator, and the
-    acceptance holds: continuous strictly beats wave on throughput and
-    p99 at the same offered load."""
-    from benchmarks import loadgen, schema
 
-    args = ["--requests", "10", "--qps", "0.8", "--slots", "3",
-            "--seed", "3", "--json", str(tmp_path / "BENCH_serving.json")]
-    a = loadgen.main(args)
-    b = loadgen.main(args)
-    assert a == b
-    schema.validate_file(tmp_path / "BENCH_serving.json")
-    assert a["acceptance"]["throughput_gain"] > 1.0
-    assert a["acceptance"]["p99_ratio"] < 1.0
-    wave, cont = (next(r for r in a["rows"] if r["policy"] == p)
-                  for p in ("wave", "continuous"))
-    assert cont["steps"] < wave["steps"]
-    assert cont["occupancy"]["mean"] > wave["occupancy"]["mean"]
+# ------------------------------------------------------ serving report ---
+
+def test_engine_shim_stats_and_counters(lm):
+    """`serving_report` and the ``engine.requests`` counter on a serve of
+    5 requests over 2 slots."""
+    sched = Scheduler(_adapter(lm), 2)
+    obs.reset()
+    with obs.enabled_scope():
+        out = sched.serve(_reqs(5, max_new=2))
+        vals = obs.counter_values()
+    assert [len(r.out) for r in out] == [2] * 5
+    assert vals["engine.requests"] == 5
+    assert vals["serve.admits"] == 5 and vals["serve.evicts"] == 5
+    rep = sched.serving_report()
+    assert (rep["requests"], rep["slots"], rep["devices"]) == (5, 2, 1)
+    assert rep["tokens_out"] == 10 and rep["latency"] is not None
+    # 2 of 5 admitted at the first step: 3 wait behind them
+    assert rep["queue_depth"]["max"] == 3
+    assert "policy" not in rep
+
+
+@pytest.mark.parametrize("num_slots,n_requests,dp", [
+    (1, 3, None), (2, 5, None), (4, 4, None), (3, 5, 4)])
+def test_serving_report_invariants(lm, num_slots, n_requests, dp):
+    """The report's fields hold their invariants on real serves: ordered
+    latency percentiles, occupancy in [0, 1], integer queue depths, and
+    output tokens that sum over the requests."""
+    mesh = _dp4_mesh() if dp else None
+    sched = Scheduler(_adapter(lm, mesh=mesh), num_slots, mesh=mesh)
+    out = sched.serve(_reqs(n_requests, max_new=[1, 4, 2, 5, 3][
+        :n_requests]))
+    rep = sched.serving_report()
+    lat = rep["latency"]
+    assert 0 <= lat["p50"] <= lat["p95"] <= lat["p99"] <= lat["max"]
+    assert 0 <= lat["mean"] <= lat["max"]
+    for key in ("mean", "min"):
+        assert 0 <= rep["occupancy"][key] <= 1
+    assert rep["occupancy"]["min"] <= rep["occupancy"]["mean"]
+    assert isinstance(rep["queue_depth"]["max"], int)
+    assert 0 <= rep["queue_depth"]["mean"] <= rep["queue_depth"]["max"]
+    assert rep["tokens_out"] == sum(len(r.out) for r in out) == \
+        sum(r["tokens_out"] for r in sched.request_log)
+    assert rep["requests"] == n_requests
+    assert rep["steps"] == len(sched.step_log) >= 1
+    assert rep["devices"] == (dp or 1)
+    for step in sched.step_log:
+        assert len(step["per_device"]) == rep["devices"]
+        assert all(0 <= u <= 1 for u in step["per_device"])
+
+
+# ---------------------------------------------------------- the LM CLI ---
+
+@pytest.mark.parametrize("mesh_arg", [None, "4,1"])
+def test_serve_cli_in_process(monkeypatch, capsys, mesh_arg):
+    """`python -m repro.launch.serve --smoke --quant w4a8` serves the
+    same tokens as a one-slot meshless serve of the same requests, and
+    with ``--mesh`` prints each device's mean slot occupancy."""
+    from repro.launch import serve as serve_cli
+
+    if mesh_arg:
+        _dp4_mesh()
+    argv = ["serve", "--arch", "qwen2.5-3b", "--smoke", "--quant", "w4a8",
+            "--requests", "3", "--batch", "2", "--max-new", "3",
+            "--max-len", "32"]
+    if mesh_arg:
+        argv += ["--mesh", mesh_arg]
+    monkeypatch.setattr(sys, "argv", argv)
+    monkeypatch.setattr(serve_cli, "enable_compile_cache", lambda: None)
+    got = serve_cli.main()
+    printed = capsys.readouterr().out
+
+    cfg = dataclasses.replace(smoke_config(), kv_quant_bits=16)
+    model, params, _, _ = serve_cli.build_serving(cfg, quant="w4a8")
+    want = Scheduler(LMDecodeAdapter(model, params, 32), 1).serve(
+        serve_cli.make_requests(cfg, 3, 3))
+    assert _outs(got) == _outs(want)
+    assert "request latency: p50=" in printed
+    if mesh_arg:
+        assert "mesh: data=4 model=1" in printed
+        assert "per-device occupancy: mean " in printed
+    else:
+        assert "per-device occupancy" not in printed

@@ -11,8 +11,9 @@
   oracle, grid-preserving max pool;
 * depthwise lowering: block-diagonal im2col+qdot vs per-group qconv vs
   an independent numpy depthwise oracle, all bit-exact;
-* the conv calibration tap (`calibrate_vision`) and the VisionEngine's
-  wave sharding/utilization accounting.
+* the conv calibration tap (`calibrate_vision`), and serving through the
+  `Scheduler` over a `VisionAdapter`: dp sharding and per-device slot
+  occupancy.
 """
 import numpy as np
 import pytest
@@ -331,10 +332,17 @@ def test_quantize_net_missing_absmax_raises(art):
 
 # --------------------------------------------------------------- engine ---
 
+def _serve(qnet, images, num_slots, mesh):
+    from repro.serve.runtime import Scheduler, VisionAdapter
+
+    sched = Scheduler(VisionAdapter(qnet, mesh=mesh, backend="xla"),
+                      num_slots, mesh=mesh)
+    return np.stack(sched.serve(list(images))), sched
+
+
 def test_vision_engine_waves_and_utilization(art):
-    """Ragged 6-request list in waves of 4 on a dp=2 mesh: outputs equal
-    the meshless forward and the utilization means are exact."""
-    from repro.serve.engine import VisionEngine
+    """Ragged 6-request list over 4 slots on a dp=2 mesh: outputs equal
+    the meshless forward and the per-device occupancy means are exact."""
 
     cfg, params, _, absmax, x = art["resnet8"]
     qnet = quantize_net(cfg, params, absmax)
@@ -343,24 +351,23 @@ def test_vision_engine_waves_and_utilization(art):
         np.float32)
     mesh = make_mesh((2, 1), ("data", "model"),
                      devices=jax.devices()[:2])
-    eng = VisionEngine(qnet, batch_size=4, mesh=mesh, backend="xla")
-    got = eng.run(images)
+    got, sched = _serve(qnet, images, 4, mesh)
     want = np.asarray(forward_int(
         qnet, quantize_input(qnet, images), backend="xla"))
     assert np.array_equal(got, want)
-    rep = eng.utilization_report()
-    # wave 1: 4/4 real -> [1, 1]; wave 2: 2/4 -> [1, 0]
-    assert rep["waves"] == 2 and rep["devices"] == 2
-    assert rep["per_device"] == [1.0, 0.5]
-    assert rep["mean_util"] == pytest.approx(0.75)
-    assert eng.artifact_bytes() == vision_artifact_bytes(qnet)
+    # step 1 runs 4 images on 4 slots -> [1, 1]; step 2 runs the last 2
+    # on the lowest free slots, both on device 0 -> [1, 0]
+    assert [s["per_device"] for s in sched.step_log] == [[1.0, 1.0],
+                                                         [1.0, 0.0]]
+    per = np.mean([s["per_device"] for s in sched.step_log], axis=0)
+    assert per.tolist() == [1.0, 0.5]
+    assert sched.serving_report()["devices"] == 2
 
 
 def test_vision_engine_ragged_batch_over_dp(art):
-    """batch_size % dp != 0 no longer raises: the slot array is padded
-    to whole per-device blocks and results still equal the meshless
-    forward (the pads never reach admission)."""
-    from repro.serve.engine import VisionEngine
+    """num_slots % dp != 0 does not raise: the slot array is padded to
+    whole per-device blocks and results still equal the meshless forward
+    (the pads never reach admission)."""
 
     cfg, params, _, absmax, _ = art["resnet8"]
     qnet = quantize_net(cfg, params, absmax)
@@ -369,12 +376,13 @@ def test_vision_engine_ragged_batch_over_dp(art):
         np.float32)
     mesh = make_mesh((4, 1), ("data", "model"),
                      devices=jax.devices()[:4])
-    eng = VisionEngine(qnet, batch_size=3, mesh=mesh, backend="xla")
-    got = eng.run(images)
+    got, sched = _serve(qnet, images, 3, mesh)
     want = np.asarray(forward_int(
         qnet, quantize_input(qnet, images), backend="xla"))
     assert np.array_equal(got, want)
-    assert eng.utilization_report()["devices"] == 4
+    assert sched.serving_report()["devices"] == 4
+    # the fourth device holds only the pad slot
+    assert sched.step_log[0]["per_device"] == [1.0, 1.0, 1.0, 0.0]
 
 
 # ------------------------------------------------------------ CLI (slow) ---
